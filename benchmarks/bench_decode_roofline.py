@@ -5,18 +5,17 @@ Four ablation cells over the paged engine on the tiny model —
 
   prefill   wall ms for one ``start`` over B long prompts, scored as
             achieved model TFLOP/s (2 * params * tokens matmul proxy)
-            against ``PEAK_FLOPS`` from launch/hlo_stats.py.
+            against the chip's bf16 peak (launch/hlo_stats.py ``PEAKS``).
   AR step   wall ms per decode step over ``DECODE_STEPS`` steps, scored
             as achieved GB/s (weights + live KV bytes touched per step —
             decode is memory-bound, so this is the roofline axis that
-            matters) against ``HBM_BW``.
+            matters) against the chip's HBM bandwidth.
 
-Honesty note: the roofline constants are TPU v5e.  On a CPU host the
-Pallas kernel runs in *interpret mode*, so kernel-cell timings measure the
-interpreter, not the kernel — the JSON records ``backend`` and sets
-``roofline_meaningful`` false off-TPU.  The cross-cell *ratios* (kernel vs
-gather, int8 vs fp) and the accuracy/capacity ablations are meaningful
-everywhere.
+The peaks are looked up by the device's ``device_kind``; a TPU with no
+entry raises.  On the CPU there is no roofline: the Pallas kernel runs in
+interpret mode, kernel-cell timings measure the interpreter, and the
+roofline fractions are null.  The JSON records the platform and device kind.
+Only the accuracy and capacity ablations mean anything on the CPU.
 
 int8 ablation extras: KV pool capacity ratio (bytes fp / bytes int8) and
 max |log-softmax| drift of the prefill logits vs the fp gather oracle.
@@ -35,7 +34,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.tokenizer import default_tokenizer
-from repro.launch.hlo_stats import HBM_BW, PEAK_FLOPS
+from repro.launch.hlo_stats import peaks
 from repro.models import Model
 from repro.serving.engine import GenerationEngine
 
@@ -70,7 +69,7 @@ def _kv_pool_bytes(cache) -> int:
     return _tree_bytes(cache, is_pool)
 
 
-def _measure_cell(model, params, tok, prompts, n_params, **engine_kw):
+def _measure_cell(model, params, tok, prompts, n_params, peak, **engine_kw):
     eng = GenerationEngine(model, params, pad_id=tok.pad_id,
                            stop_ids=(), max_len=MAX_LEN, temperature=1.0,
                            cache_mode="paged", page_size=PAGE_SIZE,
@@ -109,10 +108,12 @@ def _measure_cell(model, params, tok, prompts, n_params, **engine_kw):
     return {
         "prefill_ms": prefill_s * 1e3,
         "prefill_tflops_per_s": prefill_flops / prefill_s / 1e12,
-        "prefill_roofline_frac": prefill_flops / prefill_s / PEAK_FLOPS,
+        "prefill_roofline_frac": (prefill_flops / prefill_s
+                                  / peak["bf16_flops"] if peak else None),
         "ar_step_ms": step_s * 1e3,
         "ar_step_gb_per_s": step_bytes / step_s / 1e9,
-        "ar_step_roofline_frac": step_bytes / step_s / HBM_BW,
+        "ar_step_roofline_frac": (step_bytes / step_s / peak["hbm_bw"]
+                                  if peak else None),
         "kv_pool_bytes": kv_bytes,
         "kernel_in_use": bool(eng._use_paged_kernel),
     }, prefill_logits
@@ -127,12 +128,13 @@ def run():
     base = tok.encode("roofline probe prompt " * 12)
     prompts = [list(base[:PROMPT_TOKENS - i]) for i in range(BATCH)]
 
-    backend = jax.default_backend()
+    dev = jax.devices()[0]
+    peak = peaks(dev.device_kind) if dev.platform == "tpu" else None
     out = {
-        "backend": backend,
-        "roofline_meaningful": backend == "tpu",
-        "hardware": {"peak_flops": PEAK_FLOPS, "hbm_bw": HBM_BW,
-                     "chip": "tpu_v5e"},
+        "backend": dev.platform,
+        "roofline_meaningful": peak is not None,
+        "hardware": {"platform": dev.platform, "kind": dev.device_kind,
+                     "count": len(jax.devices()), "peaks": peak},
         "config": {"batch": BATCH, "prompt_tokens": PROMPT_TOKENS,
                    "decode_steps": DECODE_STEPS, "max_len": MAX_LEN,
                    "page_size": PAGE_SIZE, "model": "tiny",
@@ -141,31 +143,32 @@ def run():
     }
     logits = {}
     for name, kw in CELLS:
-        kw = dict(kw)
-        if kw.get("paged_kernel"):
-            kw["paged_interpret"] = backend != "tpu"
+        if peak is not None and kw.get("paged_kernel") is False:
+            continue                # on TPU the gather is not a serving path
         out["cells"][name], logits[name] = _measure_cell(
-            model, params, tok, prompts, n_params, **kw)
+            model, params, tok, prompts, n_params, peak, **kw)
 
-    oracle = logits["gather_fp"]
-    for name in ("kernel_fp", "gather_int8", "kernel_int8"):
-        out["cells"][name]["prefill_logit_maxdiff_vs_fp_oracle"] = float(
-            np.max(np.abs(logits[name] - oracle)))
+    # the fp gather is the oracle on the CPU; on TPU, where the gather is not
+    # a serving path, the fp kernel is
+    oracle_name = "gather_fp" if "gather_fp" in logits else "kernel_fp"
+    for name in logits:
+        if name != oracle_name:
+            out["cells"][name]["prefill_logit_maxdiff_vs_fp_oracle"] = float(
+                np.max(np.abs(logits[name] - logits[oracle_name])))
 
+    cells = out["cells"]
     out["ablations"] = {
-        "kernel_vs_gather_ar_step_ratio":
-            out["cells"]["gather_fp"]["ar_step_ms"]
-            / out["cells"]["kernel_fp"]["ar_step_ms"],
-        "int8_kv_capacity_ratio":
-            out["cells"]["gather_fp"]["kv_pool_bytes"]
-            / out["cells"]["gather_int8"]["kv_pool_bytes"],
+        "int8_kv_capacity_ratio": (cells["kernel_fp"]["kv_pool_bytes"]
+                                   / cells["kernel_int8"]["kv_pool_bytes"]),
         "int8_logit_maxdiff":
-            out["cells"]["gather_int8"]
-               ["prefill_logit_maxdiff_vs_fp_oracle"],
-        "kernel_fp_logit_maxdiff":
-            out["cells"]["kernel_fp"]
-               ["prefill_logit_maxdiff_vs_fp_oracle"],
+            cells["kernel_int8"]["prefill_logit_maxdiff_vs_fp_oracle"],
     }
+    if "gather_fp" in cells:
+        out["ablations"]["kernel_vs_gather_ar_step_ratio"] = (
+            cells["gather_fp"]["ar_step_ms"]
+            / cells["kernel_fp"]["ar_step_ms"])
+        out["ablations"]["kernel_fp_logit_maxdiff"] = (
+            cells["kernel_fp"]["prefill_logit_maxdiff_vs_fp_oracle"])
     return out
 
 
@@ -185,11 +188,8 @@ def main():
         rows.append((f"decode_roofline_{name}", m["ar_step_ms"] * 1e3,
                      f"gbps={m['ar_step_gb_per_s']:.2f}"))
     a = r["ablations"]
-    print(f"bench_decode_roofline,ablations,"
-          f"kernel_vs_gather={a['kernel_vs_gather_ar_step_ratio']:.2f}x,"
-          f"int8_capacity={a['int8_kv_capacity_ratio']:.1f}x,"
-          f"int8_maxdiff={a['int8_logit_maxdiff']:.3f},"
-          f"kernel_fp_maxdiff={a['kernel_fp_logit_maxdiff']:.2e}")
+    print("bench_decode_roofline,ablations," + ",".join(
+        f"{k}={v:.3g}" for k, v in a.items()))
     rows.append(("decode_roofline_int8_capacity", 0.0,
                  f"{a['int8_kv_capacity_ratio']:.1f}x_kv_on_same_hbm"))
     return rows

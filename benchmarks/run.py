@@ -57,15 +57,20 @@ def main(argv=None) -> int:
                   file=sys.stderr)
             return 2
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     all_rows = []
+    failed = []
     for label, modname in selected:
         print(f"===== {label} =====", flush=True)
         t0 = time.monotonic()
         try:
             rows = _resolve(modname).main() or []
-        # a missing artifact must not kill the harness; the row shows ERROR
+        # one failing bench must not hide the others' rows; the run still
+        # exits non-zero below
         except Exception as e:  # lint: disable=broad-except
             print(f"{label},ERROR,{type(e).__name__}: {e}")
+            failed.append(label)
             rows = []
         all_rows.extend(rows)
         print(f"({label} took {time.monotonic()-t0:.0f}s)", flush=True)
@@ -73,6 +78,9 @@ def main(argv=None) -> int:
     print("\nname,us_per_call,derived")
     for name, us, derived in all_rows:
         print(f"{name},{us:.1f},{derived}")
+    if failed:
+        print(f"FAILED: {', '.join(failed)}", file=sys.stderr)
+        return 1
     return 0
 
 

@@ -12,6 +12,7 @@ from repro.configs import get_config
 from repro.core import (ModelJudgeReward, RewardComposer, RolloutConfig,
                         RolloutWorker, RuleReward, ToolVerifyReward)
 from repro.data.tokenizer import default_tokenizer
+from repro.launch.cache import enable_compile_cache
 from repro.models import Model
 from repro.serving.engine import GenerationEngine
 from repro.tools.search_env import SearchEnv
@@ -58,4 +59,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

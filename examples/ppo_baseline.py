@@ -17,6 +17,7 @@ from repro.core.mdp import to_training_batch
 from repro.core.ppo import (PPOConfig, init_ppo_params, make_ppo_train_step,
                             value_head_apply)
 from repro.data.tokenizer import default_tokenizer
+from repro.launch.cache import enable_compile_cache
 from repro.models import Model
 from repro.models import transformer as T
 from repro.optim.adamw import AdamWConfig, adamw_init
@@ -74,4 +75,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

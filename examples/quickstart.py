@@ -12,6 +12,7 @@ from repro.core import (GRPOConfig, RewardComposer, RolloutConfig,
                         make_grpo_train_step)
 from repro.core.mdp import to_training_batch
 from repro.data.tokenizer import default_tokenizer
+from repro.launch.cache import enable_compile_cache
 from repro.models import Model
 from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.serving.engine import GenerationEngine
@@ -67,4 +68,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

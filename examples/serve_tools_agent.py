@@ -15,6 +15,7 @@ import jax
 from repro.configs import get_config
 from repro.core import RolloutConfig, RolloutWorker
 from repro.data.tokenizer import default_tokenizer
+from repro.launch.cache import enable_compile_cache
 from repro.models import Model
 from repro.serving.engine import GenerationEngine
 from repro.tools.search_env import SearchEnv
@@ -61,4 +62,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

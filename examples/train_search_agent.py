@@ -28,6 +28,7 @@ from repro.core import (GRPOConfig, RewardComposer, RolloutConfig, RuleReward,
 from repro.core.mdp import to_training_batch
 from repro.core.sft import make_expert_trajectories, make_sft_train_step
 from repro.data.tokenizer import default_tokenizer
+from repro.launch.cache import enable_compile_cache
 from repro.models import Model
 from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.tools.search_env import SearchEnv
@@ -119,4 +120,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

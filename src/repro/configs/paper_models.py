@@ -19,6 +19,7 @@ QWEN3_4B = register(ModelConfig(
     vocab_size=151936,
     qk_norm=True,
     rope_theta=1e6,
+    tie_embeddings=True,        # HF Qwen/Qwen3-4B: "tie_word_embeddings": true
     long_context_window=32768,
 ))
 

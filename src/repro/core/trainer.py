@@ -140,8 +140,12 @@ class Learner:
         self.max_seq_len = max_seq_len
         self.engine = engine
         self.ref_params = ref_params          # frozen; None => no KL
+        # the optimizer state (argument 1) belongs to this learner alone and
+        # is donated: its two f32 moments are 8 bytes per parameter, which a
+        # step would otherwise hold twice.  The params are not donated —
+        # the engine's WeightStore may still be serving them.
         self._train_step = jax.jit(make_grpo_train_step(
-            model, opt_cfg, grpo_cfg))
+            model, opt_cfg, grpo_cfg), donate_argnums=1)
         self._ref_logprob_fn = jax.jit(self._ref_logprobs_impl)
         self.n_updates = 0
         # masked per-token version lag of the last micro-batch (host copy,

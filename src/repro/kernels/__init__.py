@@ -1,3 +1,14 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels: one module per kernel, jitted wrappers in ops.py and
+pure-jnp oracles in ref.py.
+
+One rule decides how a kernel runs, read from the backend and from nothing
+else: it compiles through Mosaic on a TPU and runs in interpret mode on the
+CPU, where the tests and parity oracles run.
+"""
+import jax
+
+
+def interpret_mode() -> bool:
+    """True on the CPU backend (interpret the kernel), False on a TPU
+    (compile it)."""
+    return jax.default_backend() == "cpu"

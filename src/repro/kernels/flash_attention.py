@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG_INF = -1e30
 
 
@@ -94,8 +96,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_fwd(q, k, v, *, window: int = 0, causal: bool = True,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True):
-    """q (B,S,H,D), k/v (B,S,Hk,D) -> (B,S,H,D)."""
+                        interpret: bool | None = None):
+    """q (B,S,H,D), k/v (B,S,Hk,D) -> (B,S,H,D).  ``interpret=None`` follows
+    ``repro.kernels.interpret_mode``."""
+    if interpret is None:
+        interpret = kernels.interpret_mode()
     B, S, H, D = q.shape
     Hk = k.shape[2]
     G = H // Hk

@@ -1,27 +1,19 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels always run in interpret mode (the kernel
-body executes as traced jnp ops); on a real TPU set REPRO_PALLAS_COMPILE=1 to
-lower them through Mosaic.
+Each kernel compiles on a TPU and runs in interpret mode on the CPU (the
+kernel body executes as traced jnp ops); ``repro.kernels.interpret_mode`` is
+the one place that decides.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.paged_attention import paged_attention_fwd
 from repro.kernels.ssd_scan import ssd_scan_fwd
 from repro.kernels.token_logprob import fused_token_logprob_fwd
-
-
-def _interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("window", "causal", "block_q",
@@ -30,32 +22,28 @@ def flash_attention(q, k, v, window: int = 0, causal: bool = True,
                     block_q: int = 128, block_k: int = 128):
     """Causal GQA flash attention. q (B,S,H,D), k/v (B,S,Hk,D) -> (B,S,H,D)."""
     return flash_attention_fwd(q, k, v, window=window, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               interpret=_interpret())
+                               block_q=block_q, block_k=block_k)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def paged_attention(q, k_pool, v_pool, table, q_pos, k_scale=None,
-                    v_scale=None, interpret=None):
+                    v_scale=None):
     """Paged single-token decode attention over a block-table KV pool.
 
-    q (B,H,D), k_pool/v_pool (N,bs,Hk,·) with trash block last, table (B,T)
-    int32, q_pos (B,) int32 -> (B,H,Dv).  The block table is a scalar-prefetch
-    operand, so K/V blocks stream from HBM in table order with no gather copy.
-    int8 pools pass per-slot f32 ``k_scale``/``v_scale`` (N,bs,Hk); the
-    kernel dequantizes in its inner loop.  ``interpret=None`` auto-detects
-    (interpret everywhere but TPU; REPRO_PALLAS_COMPILE=1 forces lowering).
+    q (B,H,D), head-major k_pool/v_pool (N,Hk,bs,·) with trash block last,
+    table (B,T) int32, q_pos (B,) int32 -> (B,H,Dv).  The block table is a
+    scalar-prefetch operand, so K/V blocks stream from HBM in table order
+    with no gather copy.  int8 pools pass per-slot f32 ``k_scale``/``v_scale``
+    (N,Hk,bs); the kernel dequantizes in its inner loop.
     """
-    return paged_attention_fwd(
-        q, k_pool, v_pool, table, q_pos, k_scale=k_scale, v_scale=v_scale,
-        interpret=_interpret() if interpret is None else interpret)
+    return paged_attention_fwd(q, k_pool, v_pool, table, q_pos,
+                               k_scale=k_scale, v_scale=v_scale)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x, dt, A_log, Bm, Cm, chunk: int = 64, D=None):
     """Mamba2 SSD chunked scan. Returns (y, final_state)."""
-    return ssd_scan_fwd(x, dt, A_log, Bm, Cm, chunk=chunk, D=D,
-                        interpret=_interpret())
+    return ssd_scan_fwd(x, dt, A_log, Bm, Cm, chunk=chunk, D=D)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_v"))
@@ -63,4 +51,4 @@ def fused_token_logprob(logits, labels, block_rows: int = 256,
                         block_v: int = 2048):
     """Streaming log p(label) without materializing log-softmax."""
     return fused_token_logprob_fwd(logits, labels, block_rows=block_rows,
-                                   block_v=block_v, interpret=_interpret())
+                                   block_v=block_v)

@@ -18,9 +18,14 @@ paging exists to avoid.  Here the *grid itself* walks the table:
     sublane dim so the (G, bs) score tile feeds the MXU;
   * online-softmax state (m, l, acc) persists across the sequentially
     executed table_slot dimension in VMEM scratch, as in flash attention;
-  * int8 pools carry per-(block, slot, kv_head) f32 scales alongside the
-    values; the inner loop dequantizes each fetched tile, so the HBM read
-    per cached token is halved relative to bf16 and quartered vs f32.
+  * int8 pools carry per-(block, kv_head, slot) f32 scales alongside the
+    values; a block's scales for all kv heads arrive as one (Hk, bs) tile
+    and the inner loop applies this head's row to the scores (K) and the
+    softmax weights (V), so the HBM read per cached token is halved
+    relative to bf16 and quartered vs f32;
+  * every block's last two dims are either (8, 128)-aligned or whole array
+    dims, as Mosaic requires: the pools are head-major (N, Hk, bs, D), so a
+    K/V block is one head's (bs, D) tile, and the softmax state is kept 2-D.
 
 Slot ``i`` of the block at table slot ``j`` holds absolute position
 ``j*bs + i`` by construction (models/attention.py writes position p to block
@@ -30,30 +35,22 @@ current occupant, and stale slots from a previous occupant always sit at
 masked positions.  Rows with ``q_pos < 0`` (dead/padded lanes) compute no
 block at all and emit exact zeros.
 
-Validated in interpret mode against kernels/ref.py::paged_attention_ref.
+Validated in interpret mode against kernels/ref.py::paged_attention_ref, and
+compiled for a described TPU v5e by tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG_INF = -1e30
-
-
-def default_interpret() -> bool:
-    """Interpret off-TPU (CPU tests / parity oracle); compile on TPU.
-
-    ``REPRO_PALLAS_COMPILE=1`` forces Mosaic lowering on any backend.
-    """
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
-    return jax.default_backend() != "tpu"
 
 
 def _paged_kernel(table_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
@@ -64,6 +61,7 @@ def _paged_kernel(table_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
         ks_ref = vs_ref = None
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
+    hk = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -77,23 +75,26 @@ def _paged_kernel(table_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(j * block_size <= q_pos)
     def _compute():
         q = q_ref[0, 0, :, :].astype(jnp.float32)            # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (bs, Dv)
-        if quantized:
-            k = k * ks_ref[0, :, 0][:, None]                 # per-slot scale
-            v = v * vs_ref[0, :, 0][:, None]
+        k = k_ref[0, 0, :, :].astype(jnp.float32)            # (bs, D)
+        v = v_ref[0, 0, :, :].astype(jnp.float32)            # (bs, Dv)
         scale = 1.0 / math.sqrt(q.shape[-1])
         s = (q * scale) @ k.T                                # (G, bs)
+        if quantized:
+            # per-slot scales of this kv head, (1, bs); a per-slot K scale
+            # scales that slot's score column, a V scale its softmax weight
+            s = s * ks_ref[0, pl.ds(hk, 1), :]
         kpos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         mask = kpos <= q_pos
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_scr[...]                                  # (G, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(mask, jnp.exp(s - m_cur[:, None]), 0.0)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + p @ v
+        p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[0, pl.ds(hk, 1), :]
+        acc_scr[...] = acc_scr[...] * alpha + p @ v
         m_scr[...] = m_cur
 
     @pl.when(j == n_table - 1)
@@ -101,8 +102,8 @@ def _paged_kernel(table_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
         # rows whose every slot was masked (q_pos < 0: dead lane, all-trash
         # table) never accumulated — emit exact zeros, not acc/eps garbage
         l = l_scr[...]
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where((l > 0.0)[:, None], out, 0.0)
+        out = acc_scr[...] / jnp.maximum(l, 1e-30)
+        out = jnp.where(l > 0.0, out, 0.0)
         o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
 
 
@@ -111,19 +112,20 @@ def paged_attention_fwd(q, k_pool, v_pool, table, q_pos, *,
                         interpret: bool | None = None):
     """Paged single-token decode attention.
 
-    q (B,H,D) — the newest token's queries; k_pool (N,bs,Hk,D),
-    v_pool (N,bs,Hk,Dv) — global block pools whose last block is trash;
+    q (B,H,D) — the newest token's queries; k_pool (N,Hk,bs,D),
+    v_pool (N,Hk,bs,Dv) — head-major global block pools whose last block is
+    trash, so one kv head's block is a ``(bs, D)`` tile;
     table (B,T) int32 block table (-1 = unallocated); q_pos (B,) int32 —
     each row's query position (the row's cache holds positions
     ``0..q_pos`` inclusive; ``q_pos < 0`` => dead row, output is exact
-    zeros).  int8 pools pass ``k_scale``/``v_scale`` (N,bs,Hk) f32
-    per-slot dequant scales.  ``interpret=None`` auto-detects the backend
-    (interpret everywhere but TPU).  Returns (B,H,Dv) in q's dtype.
+    zeros).  int8 pools pass ``k_scale``/``v_scale`` (N,Hk,bs) f32
+    per-slot dequant scales.  ``interpret=None`` follows
+    ``repro.kernels.interpret_mode``.  Returns (B,H,Dv) in q's dtype.
     """
     if interpret is None:
-        interpret = default_interpret()
+        interpret = kernels.interpret_mode()
     B, H, D = q.shape
-    N, bs, Hk, _ = k_pool.shape
+    N, Hk, bs, _ = k_pool.shape
     Dv = v_pool.shape[-1]
     T = table.shape[1]
     G = H // Hk
@@ -133,21 +135,21 @@ def paged_attention_fwd(q, k_pool, v_pool, table, q_pos, *,
 
     def kv_map(b, hk, j, table_ref, qpos_ref):
         blk = table_ref[b * T + j]
-        return (jnp.where(blk < 0, N - 1, blk), 0, hk, 0)
+        return (jnp.where(blk < 0, N - 1, blk), hk, 0, 0)
 
     def scale_map(b, hk, j, table_ref, qpos_ref):
         blk = table_ref[b * T + j]
-        return (jnp.where(blk < 0, N - 1, blk), 0, hk)
+        return (jnp.where(blk < 0, N - 1, blk), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, G, D), lambda b, hk, j, *_: (b, hk, 0, 0)),
-        pl.BlockSpec((1, bs, 1, D), kv_map),
-        pl.BlockSpec((1, bs, 1, Dv), kv_map),
+        pl.BlockSpec((1, 1, bs, D), kv_map),
+        pl.BlockSpec((1, 1, bs, Dv), kv_map),
     ]
     operands = [qh, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs, 1), scale_map),
-                     pl.BlockSpec((1, bs, 1), scale_map)]
+        in_specs += [pl.BlockSpec((1, Hk, bs), scale_map),
+                     pl.BlockSpec((1, Hk, bs), scale_map)]
         operands += [k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32)]
 
@@ -158,8 +160,8 @@ def paged_attention_fwd(q, k_pool, v_pool, table, q_pos, *,
         out_specs=pl.BlockSpec((1, 1, G, Dv),
                                lambda b, hk, j, *_: (b, hk, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, Dv), jnp.float32),
         ],
     )

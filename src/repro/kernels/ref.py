@@ -37,19 +37,19 @@ def paged_attention_ref(q, k_pool, v_pool, table, q_pos):
     """Paged single-token decode attention by dense gather (ground truth for
     kernels/paged_attention.py).
 
-    q (B,H,D); k_pool (N,bs,Hk,D) / v_pool (N,bs,Hk,Dv) global block pools
+    q (B,H,D); k_pool (N,Hk,bs,D) / v_pool (N,Hk,bs,Dv) global block pools
     (last block = trash); table (B,T) int32 (-1 = unallocated); q_pos (B,)
     the query's absolute position.  Slot i of table slot j holds position
     j*bs+i, so the mask is simply pos <= q_pos (unallocated slots gather the
     trash block but sit beyond q_pos for any live row).  Returns (B,H,Dv).
     """
     B, H, D = q.shape
-    N, bs, Hk, _ = k_pool.shape
+    N, Hk, bs, _ = k_pool.shape
     T = table.shape[1]
     G = H // Hk
     ids = jnp.where(table < 0, N - 1, table)                  # (B,T)
-    k = k_pool[ids].transpose(0, 3, 1, 2, 4).reshape(B, Hk, T * bs, D)
-    v = v_pool[ids].transpose(0, 3, 1, 2, 4).reshape(B, Hk, T * bs,
+    k = k_pool[ids].transpose(0, 2, 1, 3, 4).reshape(B, Hk, T * bs, D)
+    v = v_pool[ids].transpose(0, 2, 1, 3, 4).reshape(B, Hk, T * bs,
                                                      v_pool.shape[-1])
     pos = (jnp.arange(T)[:, None] * bs + jnp.arange(bs)[None, :]).reshape(-1)
     mask = pos[None, :] <= q_pos[:, None]                     # (B, T*bs)

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 
 def _ssd_kernel(x_ref, dtc_ref, b_ref, c_ref, y_ref, state_out_ref, h_scr, *,
                 chunk: int):
@@ -66,11 +68,14 @@ def _ssd_kernel(x_ref, dtc_ref, b_ref, c_ref, y_ref, state_out_ref, h_scr, *,
 
 
 def ssd_scan_fwd(x, dt, A_log, Bm, Cm, *, chunk: int = 64, D=None,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """x (B,S,H,P), dt (B,S,H) post-softplus, Bm/Cm (B,S,G,N).
+    ``interpret=None`` follows ``repro.kernels.interpret_mode``.
 
     Returns (y (B,S,H,P) f32, final_state (B,H,P,N) f32).
     """
+    if interpret is None:
+        interpret = kernels.interpret_mode()
     Bsz, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
     rep = H // G

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG_INF = -1e30
 
 
@@ -62,8 +64,12 @@ def _logprob_kernel(logits_ref, labels_ref, out_ref, m_scr, l_scr, xl_scr, *,
 
 
 def fused_token_logprob_fwd(logits, labels, *, block_rows: int = 256,
-                            block_v: int = 2048, interpret: bool = True):
-    """logits (B,S,V), labels (B,S) int32 -> logprob (B,S) f32."""
+                            block_v: int = 2048,
+                            interpret: bool | None = None):
+    """logits (B,S,V), labels (B,S) int32 -> logprob (B,S) f32.
+    ``interpret=None`` follows ``repro.kernels.interpret_mode``."""
+    if interpret is None:
+        interpret = kernels.interpret_mode()
     B, S, V = logits.shape
     R = B * S
     lf = logits.reshape(R, V)

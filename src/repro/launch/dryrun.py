@@ -1,5 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# the sweep compiles on 512 forced host devices; pinning the CPU keeps it off
+# any accelerator the host has (a chip belongs to one process at a time)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run (brief: MULTI-POD DRY-RUN).
 
@@ -37,6 +40,9 @@ from repro.models.params import tree_map_specs
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
 RESULTS_DIR = os.path.abspath(os.path.join(os.getcwd(), "results", "dryrun"))
+# the chip the sweep's meshes stand for (v5e pods); its roofline peaks come
+# from hlo_stats.PEAKS
+TARGET_KIND = "TPU v5 lite"
 
 # per-(arch,shape) microbatch counts for the gradient-accumulation scan
 # (chosen so per-device live activations fit HBM; see EXPERIMENTS.md §Perf)
@@ -349,10 +355,11 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     if extrap and "flops" in extrap:
         terms = hlo_stats.roofline_terms(
             extrap["flops"], extrap["bytes_accessed"],
-            extrap["coll_bytes"], n_chips)
+            extrap["coll_bytes"], n_chips, device_kind=TARGET_KIND)
     else:
         terms = hlo_stats.roofline_terms(cost["flops"], cost["bytes_accessed"],
-                                         coll_total, n_chips)
+                                         coll_total, n_chips,
+                                         device_kind=TARGET_KIND)
 
     B = INPUT_SHAPES[shape_name]["global_batch"]
     S = INPUT_SHAPES[shape_name]["seq_len"]

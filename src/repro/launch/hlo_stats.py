@@ -1,17 +1,32 @@
 """HLO statistics for the roofline: collective bytes from the compiled module
 text, plus cost_analysis extraction and the three roofline terms.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16/chip, 819 GB/s HBM,
-~50 GB/s/link ICI (brief §ROOFLINE).
+The roofline divides by the published peaks of one chip, looked up by the
+``device_kind`` JAX reports.  A device with no entry is an error, never a
+default.
 """
 from __future__ import annotations
 
 import re
 from typing import Dict
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# Published per-chip peaks keyed by ``jax.Device.device_kind``.
+# TPU v5e (kind "TPU v5 lite"): Google Cloud documentation, "TPU v5e" —
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect over 4 links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The published peaks of ``device_kind``; raises KeyError for a kind
+    with no entry in :data:`PEAKS`."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -66,16 +81,17 @@ def while_trip_counts(hlo_text: str) -> int:
 
 
 def roofline_terms(flops: float, bytes_accessed: float, coll_bytes: int,
-                   n_chips: int) -> dict:
-    """The three terms in seconds (brief §ROOFLINE).
+                   n_chips: int, *, device_kind: str) -> dict:
+    """The three terms in seconds on one chip of ``device_kind``.
 
     flops / bytes_accessed are WHOLE-PROGRAM numbers from cost_analysis
     (already per-partition in SPMD: XLA analyses the partitioned module);
     collective bytes are whole-module per-partition too.
     """
-    t_compute = flops / PEAK_FLOPS
-    t_memory = bytes_accessed / HBM_BW
-    t_collective = coll_bytes / ICI_BW
+    p = peaks(device_kind)
+    t_compute = flops / p["bf16_flops"]
+    t_memory = bytes_accessed / p["hbm_bw"]
+    t_collective = coll_bytes / p["ici_bw"]
     dominant = max((t_compute, "compute"), (t_memory, "memory"),
                    (t_collective, "collective"))[1]
     return {

@@ -10,6 +10,15 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices):
+    """Mesh with Auto axes: ``shard_hint`` constrains activations through
+    ``with_sharding_constraint``, which accepts only Auto axes (the default
+    axis type of ``jax.make_mesh`` is Explicit)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,11 +31,10 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             f"run under launch/dryrun.py (forces "
             f"--xla_force_host_platform_device_count=512)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_debug_mesh(model: int = 2, data: int = 2):
     """Tiny mesh for CPU sharding tests (requires forced host devices)."""
     n = model * data
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:n])
+    return _auto_mesh((data, model), ("data", "model"), jax.devices()[:n])

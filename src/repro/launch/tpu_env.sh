@@ -22,6 +22,3 @@ unset _TCMALLOC
 export XLA_FLAGS="--xla_step_marker_location=1${XLA_FLAGS:+ ${XLA_FLAGS}}"
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
 
-# Force the compiled Pallas paged-attention kernel even when auto-detect
-# would pick interpret mode (debugging off-TPU lowering):
-#   export REPRO_PALLAS_COMPILE=1

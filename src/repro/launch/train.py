@@ -31,7 +31,8 @@ def main():
 
     if args.dry_run:
         # delegate to the dry-run module (it must own process start-up because
-        # of XLA_FLAGS device forcing)
+        # of XLA_FLAGS device forcing); it pins itself to the CPU, so this
+        # parent, which has not touched JAX, leaves any chip alone
         import os
         import subprocess
         import sys
@@ -45,10 +46,12 @@ def main():
     from repro.core import (GRPOConfig, RewardComposer, RolloutConfig,
                             RuleReward, RLTrainer, TrainerConfig)
     from repro.data.tokenizer import default_tokenizer
+    from repro.launch.cache import enable_compile_cache
     from repro.models import Model
     from repro.optim.adamw import AdamWConfig
     from repro.tools.search_env import SearchEnv
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     model = Model(cfg)
     tok = default_tokenizer(cfg.vocab_size)

@@ -13,7 +13,7 @@ contiguous (one lane per batch row)::
 paged (vLLM-style global block pool + per-row block table; the ``table`` key
 marks the layout)::
 
-  GQA : {"k": (N+1,bs,Hk,D), "v": (N+1,bs,Hk,Dv), "pos": (N+1,bs),
+  GQA : {"k": (N+1,Hk,bs,D), "v": (N+1,Hk,bs,Dv), "pos": (N+1,bs),
          "table": (B,T) int32}
   MLA : {"ckv": (N+1,bs,R), "krope": (N+1,bs,Dr), "pos": (N+1,bs),
          "table": (B,T) int32}
@@ -23,7 +23,9 @@ positions ``[j*bs, (j+1)*bs)``; -1 = unallocated.  The last pool block
 (index N) is the *trash block*: writes for invalid positions (right-pads,
 inactive decode rows) are routed there so they can never corrupt a live
 row's block, and -1 table entries gather it (its ``pos`` is always -1, so
-it is never attended).  Block allocation itself is host-side
+it is never attended).  GQA pools are head-major so that one kv head's
+block is a contiguous ``(bs, D)`` tile, the block the decode kernel fetches
+(kernels/paged_attention.py).  Block allocation itself is host-side
 (serving.engine.BlockAllocator); this module only scatters/gathers through
 the table.  Paged layout requires window=0 (full attention).
 
@@ -132,8 +134,8 @@ def init_paged_kv_cache(cfg, num_blocks: int, block_size: int, batch: int,
     blocks + 1 trash block, and a (batch, max_blocks_per_row) block table
     initialized to -1 (unallocated).
 
-    ``kv_dtype="int8"`` stores pool values as int8 with per-(block, slot
-    [, kv_head]) f32 absmax scales in ``<leaf>_scale`` companions — half
+    ``kv_dtype="int8"`` stores pool values as int8 with per-(block[,
+    kv_head], slot) f32 absmax scales in ``<leaf>_scale`` companions — half
     the bytes per cached token vs bf16 (quarter vs f32), so the same HBM
     holds twice the blocks.  Values are quantized on cache write and
     dequantized on read (kernel inner loop / gather); fp stays the default
@@ -160,8 +162,8 @@ def init_paged_kv_cache(cfg, num_blocks: int, block_size: int, batch: int,
                                                    jnp.float32)
         return cache
     cache = {
-        "k": jnp.zeros((n, block_size, cfg.n_kv_heads, cfg.head_dim), dt),
-        "v": jnp.zeros((n, block_size, cfg.n_kv_heads, cfg.v_dim), dt),
+        "k": jnp.zeros((n, cfg.n_kv_heads, block_size, cfg.head_dim), dt),
+        "v": jnp.zeros((n, cfg.n_kv_heads, block_size, cfg.v_dim), dt),
         "pos": pos,
         "table": table,
     }
@@ -169,7 +171,7 @@ def init_paged_kv_cache(cfg, num_blocks: int, block_size: int, batch: int,
         for name in ("k", "v"):
             cache[name] = cache[name].astype(jnp.int8)
             cache[name + "_scale"] = jnp.zeros(
-                (n, block_size, cfg.n_kv_heads), jnp.float32)
+                (n, cfg.n_kv_heads, block_size), jnp.float32)
     return cache
 
 
@@ -197,7 +199,8 @@ def _paged_scatter(cache: dict, kv_leaves: dict, positions: jax.Array,
                    kv_valid) -> dict:
     """Scatter new tokens through the block table into the pool.
 
-    ``kv_leaves`` maps leaf name -> (B,Q,...) new values.  Writes for
+    ``kv_leaves`` maps leaf name -> (B,Q,...) new values; per-head values
+    (B,Q,Hk,D) land in a head-major pool (N,Hk,bs,D).  Writes for
     invalid entries (``kv_valid`` False or an unallocated table slot) go to
     the trash block — the last pool block, which no table ever references
     with a valid id — so a pad can never touch a live block.  int8 pools
@@ -225,13 +228,13 @@ def _paged_scatter(cache: dict, kv_leaves: dict, positions: jax.Array,
 
     new_cache = dict(cache)
     for name, new in kv_leaves.items():
+        at = ((ids_w, slice(None), off) if new.ndim == 4 else (ids_w, off))
         if name + "_scale" in cache:
             qv, sc = _quantize_int8(new)
-            new_cache[name] = cache[name].at[ids_w, off].set(qv)
-            new_cache[name + "_scale"] = cache[name + "_scale"].at[
-                ids_w, off].set(sc)
+            new_cache[name] = cache[name].at[at].set(qv)
+            new_cache[name + "_scale"] = cache[name + "_scale"].at[at].set(sc)
         else:
-            new_cache[name] = cache[name].at[ids_w, off].set(
+            new_cache[name] = cache[name].at[at].set(
                 new.astype(cache[name].dtype))
     new_cache["pos"] = cache["pos"].at[ids_w, off].set(store_pos)
     return new_cache
@@ -247,10 +250,11 @@ def _paged_gather(cache: dict, names, out_dtype) -> tuple:
 
     The gather materializes each row's K/V contiguously per call —
     XLA-friendly and exact, but per-step HBM traffic still scales with
-    table width.  On real TPUs the decode hot path uses
-    kernels/paged_attention.py (ops.paged_attention) instead, which streams
-    pool blocks via a scalar-prefetched table with no gather copy; this
-    gather remains the interpret/CPU fallback and the parity oracle.
+    table width.  The GQA decode path on TPU uses kernels/paged_attention.py
+    (ops.paged_attention) instead, which streams pool blocks via a
+    scalar-prefetched table with no gather copy.  This gather serves
+    prefill, MLA (its only implementation), and GQA decode on the CPU,
+    where it is the kernel's parity oracle.
     """
     table = cache["table"]
     B = table.shape[0]
@@ -258,10 +262,12 @@ def _paged_gather(cache: dict, names, out_dtype) -> tuple:
     gather_ids = jnp.where(table < 0, trash, table)          # (B, T)
     gathered = {}
     for name in names:
-        g = cache[name][gather_ids]                          # (B, T, bs, ...)
+        g = cache[name][gather_ids]                  # (B, T, [Hk,] bs, F)
         if name + "_scale" in cache:
-            sc = cache[name + "_scale"][gather_ids]          # (B, T, bs, ...)
+            sc = cache[name + "_scale"][gather_ids]  # (B, T, [Hk,] bs)
             g = g.astype(jnp.float32) * sc[..., None]
+        if g.ndim == 5:                      # head-major: to (B,T,bs,Hk,F)
+            g = g.swapaxes(2, 3)
         gathered[name] = g.reshape((B, -1) + g.shape[3:]).astype(out_dtype)
     k_pos = cache["pos"][gather_ids].reshape(B, -1)          # (B, T*bs)
     return gathered, k_pos
@@ -390,7 +396,7 @@ def _causal_mask(q_pos, k_pos, window: int):
 # ---------------------------------------------------------------- GQA apply
 def gqa_apply(params, cfg, x, positions, cache=None, window: int = 0,
               causal: bool = True, use_flash: bool = False, kv_valid=None,
-              paged_kernel: bool = False, paged_interpret=None):
+              paged_kernel: bool = False):
     """x (B,Q,d), positions (B,Q).  Returns (out, new_cache).
 
     ``kv_valid`` (B,Q) bool marks right-pad positions in ragged rollout
@@ -398,8 +404,8 @@ def gqa_apply(params, cfg, x, positions, cache=None, window: int = 0,
 
     ``paged_kernel`` routes single-token paged decode (Q==1, "table" cache)
     through the Pallas block-table kernel (kernels/paged_attention.py)
-    instead of the dense pool gather; ``paged_interpret`` overrides the
-    kernel's backend auto-detect (None = interpret everywhere but TPU).
+    instead of the dense pool gather.  The kernel compiles on TPU and runs
+    in interpret mode on the CPU (``repro.kernels.interpret_mode``).
     """
     B, Q, _ = x.shape
     H, Hk, hd, vd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.v_dim
@@ -437,7 +443,7 @@ def gqa_apply(params, cfg, x, positions, cache=None, window: int = 0,
             outv = paged_attention(
                 q[:, 0], new_cache["k"], new_cache["v"], new_cache["table"],
                 q_pos, k_scale=new_cache.get("k_scale"),
-                v_scale=new_cache.get("v_scale"), interpret=paged_interpret)
+                v_scale=new_cache.get("v_scale"))
             out = outv[:, None].astype(dt)                   # (B,1,H,vd)
             out = jnp.einsum("bqhe,hed->bqd", out, params["o"].astype(dt))
             return shard_hint(out, ("batch", "seq", "embed")), new_cache
@@ -625,7 +631,7 @@ def encode_cross_kv(params, cfg, enc_out):
 
 def attention_apply(params, cfg, x, positions, cache=None, window: int = 0,
                     causal: bool = True, use_flash: bool = False, kv_valid=None,
-                    paged_kernel: bool = False, paged_interpret=None):
+                    paged_kernel: bool = False):
     if cfg.uses_mla:
         # MLA decodes absorbed (scores in latent space over ckv/krope) — the
         # two-pool kernel variant is future work, so paged MLA keeps the
@@ -634,4 +640,4 @@ def attention_apply(params, cfg, x, positions, cache=None, window: int = 0,
                          kv_valid=kv_valid)
     return gqa_apply(params, cfg, x, positions, cache=cache, window=window,
                      causal=causal, use_flash=use_flash, kv_valid=kv_valid,
-                     paged_kernel=paged_kernel, paged_interpret=paged_interpret)
+                     paged_kernel=paged_kernel)

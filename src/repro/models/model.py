@@ -125,33 +125,16 @@ class PagedCache(CachePolicy):
     hybrid-mamba state keeps the per-row contiguous layout and per-row reset.
     Requires window=0 — sliding-window ring buffers stay contiguous.
 
-    Decode hot-path knobs:
-
-    * ``kv_dtype`` — "fp" (default; training-parity oracle) or "int8"
-      (quantize-on-write block pools with per-slot scales: half the bytes
-      per cached token vs bf16, so the same HBM holds 2x the blocks);
-    * ``use_kernel`` — route GQA decode through the Pallas block-table
-      kernel (kernels/paged_attention.py).  None = auto: kernel on TPU,
-      JAX gather fallback elsewhere (the gather stays the parity oracle);
-    * ``interpret`` — override the kernel's interpret/compile auto-detect
-      (forwarded to pallas_call; None = interpret everywhere but TPU).
+    ``kv_dtype`` is "fp" (default; training-parity oracle) or "int8"
+    (quantize-on-write block pools with per-slot scales: half the bytes per
+    cached token vs bf16, so the same HBM holds 2x the blocks).
     """
     block_size: int
     num_blocks: int
     kv_dtype: str = "fp"
-    use_kernel: Optional[bool] = None
-    interpret: Optional[bool] = None
 
     def max_blocks_per_row(self, max_len: int) -> int:
         return max(1, math.ceil(max_len / self.block_size))
-
-    def kernel_enabled(self) -> bool:
-        """Resolve ``use_kernel``: explicit setting, else kernel iff the
-        backend would compile it (TPU / REPRO_PALLAS_COMPILE=1)."""
-        if self.use_kernel is not None:
-            return bool(self.use_kernel)
-        from repro.kernels.paged_attention import default_interpret
-        return not default_interpret()
 
     def init_cache(self, model, batch, max_len, window=0):
         c = model.cfg
@@ -268,12 +251,13 @@ class PagedCache(CachePolicy):
 
     def set_tables(self, cache, table: np.ndarray):
         """Broadcast a fresh host block table (B, T) into every ``table``
-        leaf of the cache (tables are identical across layers)."""
-        t = jnp.asarray(table, jnp.int32)
-
+        leaf of the cache (tables are identical across layers).  Each leaf
+        gets its own buffer: the engine donates the cache to its jitted
+        steps, and one buffer cannot be donated twice."""
         def walk(tree):
             if isinstance(tree, dict):
-                return {k: (jnp.broadcast_to(t, v.shape) if k == "table"
+                return {k: (jnp.array(np.broadcast_to(table, v.shape),
+                                      jnp.int32) if k == "table"
                             else walk(v)) for k, v in tree.items()}
             if isinstance(tree, list):
                 return [walk(x) for x in tree]
@@ -326,8 +310,7 @@ class Model:
     def apply(self, params, batch: dict, caches=None, positions=None,
               window: int = 0, use_flash: bool = False, use_kernel: bool = False,
               moe_dense_ref: bool = False, kv_valid=None,
-              last_token_only=False, paged_kernel: bool = False,
-              paged_interpret=None):
+              last_token_only=False, paged_kernel: bool = False):
         """Full-sequence forward (train / prefill).
 
         Returns (logits, aux_loss, new_caches).  ``batch`` carries "tokens"
@@ -340,8 +323,7 @@ class Model:
                               caches=caches, window=window, use_flash=use_flash,
                               moe_dense_ref=moe_dense_ref, kv_valid=kv_valid,
                               last_token_only=last_token_only,
-                              paged_kernel=paged_kernel,
-                              paged_interpret=paged_interpret)
+                              paged_kernel=paged_kernel)
         if c.family == "ssm":
             return T.mamba_lm_apply(params, c, batch["tokens"],
                                     caches=caches, use_kernel=use_kernel,
@@ -353,8 +335,7 @@ class Model:
                                  use_flash=use_flash, use_kernel=use_kernel,
                                  kv_valid=kv_valid,
                                  last_token_only=last_token_only,
-                                 paged_kernel=paged_kernel,
-                                 paged_interpret=paged_interpret)
+                                 paged_kernel=paged_kernel)
         if c.family == "encdec":
             return T.encdec_apply(params, c, batch["tokens"],
                                   prefix_embeds=batch["prefix_embeds"],
@@ -362,13 +343,11 @@ class Model:
                                   window=window, use_flash=use_flash,
                                   kv_valid=kv_valid,
                                   last_token_only=last_token_only,
-                                  paged_kernel=paged_kernel,
-                                  paged_interpret=paged_interpret)
+                                  paged_kernel=paged_kernel)
         raise ValueError(c.family)
 
     def decode_step(self, params, tokens, positions, caches, window: int = 0,
-                    cross_kv=None, kv_valid=None, paged_kernel: bool = False,
-                    paged_interpret=None):
+                    cross_kv=None, kv_valid=None, paged_kernel: bool = False):
         """tokens (B,Q small), positions (B,Q) -> (logits, new_caches).
 
         Contract (the serving engine traces this inside a jitted
@@ -378,7 +357,7 @@ class Model:
         Rows with ``kv_valid=False`` must leave the sequence state untouched
         (attention stores pos=-1; SSM freezes the recurrent state via dt=0).
 
-        ``paged_kernel``/``paged_interpret`` (from ``PagedCache``) route
+        ``paged_kernel`` (resolved by the serving engine) routes
         single-token GQA decode through the Pallas block-table kernel.
         """
         c = self.cfg
@@ -386,13 +365,12 @@ class Model:
             logits, _, nc = T.encdec_decode_stack(
                 params, c, tokens, cross_kv, positions=positions,
                 caches=caches, window=window, kv_valid=kv_valid,
-                paged_kernel=paged_kernel, paged_interpret=paged_interpret)
+                paged_kernel=paged_kernel)
             return logits, nc
         logits, _, nc = self.apply(params, {"tokens": tokens}, caches=caches,
                                    positions=positions, window=window,
                                    kv_valid=kv_valid,
-                                   paged_kernel=paged_kernel,
-                                   paged_interpret=paged_interpret)
+                                   paged_kernel=paged_kernel)
         return logits, nc
 
     # ---------------------------------------------------------- caches

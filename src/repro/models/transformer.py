@@ -36,12 +36,11 @@ def decoder_layer_specs(cfg, moe_layer: bool) -> dict:
 
 def decoder_layer_apply(p, cfg, x, positions, cache=None, window: int = 0,
                         use_flash: bool = False, moe_dense_ref: bool = False,
-                        kv_valid=None, paged_kernel: bool = False,
-                        paged_interpret=None):
+                        kv_valid=None, paged_kernel: bool = False):
     h, new_cache = attn.attention_apply(
         p["attn"], cfg, L.rmsnorm(p["ln1"], x, cfg.norm_eps), positions,
         cache=cache, window=window, use_flash=use_flash, kv_valid=kv_valid,
-        paged_kernel=paged_kernel, paged_interpret=paged_interpret)
+        paged_kernel=paged_kernel)
     x = x + h
     aux = jnp.zeros((), jnp.float32)
     if "moe" in p:
@@ -94,8 +93,7 @@ def _scan_layers(stacked_params, cfg, x, layer_fn, caches=None):
 
 def decoder_stack_apply(params, cfg, x, positions, caches=None, window: int = 0,
                         use_flash: bool = False, moe_dense_ref: bool = False,
-                        kv_valid=None, paged_kernel: bool = False,
-                        paged_interpret=None):
+                        kv_valid=None, paged_kernel: bool = False):
     aux_total = jnp.zeros((), jnp.float32)
     dense_caches_new = []
     if "dense_layers" in params:
@@ -104,8 +102,7 @@ def decoder_stack_apply(params, cfg, x, positions, caches=None, window: int = 0,
             x, nc, a = decoder_layer_apply(p_l, cfg, x, positions, cache=c,
                                            window=window, use_flash=use_flash,
                                            kv_valid=kv_valid,
-                                           paged_kernel=paged_kernel,
-                                           paged_interpret=paged_interpret)
+                                           paged_kernel=paged_kernel)
             aux_total = aux_total + a
             dense_caches_new.append(nc)
 
@@ -116,8 +113,7 @@ def decoder_stack_apply(params, cfg, x, positions, caches=None, window: int = 0,
                                    window=window, use_flash=use_flash,
                                    moe_dense_ref=moe_dense_ref,
                                    kv_valid=kv_valid,
-                                   paged_kernel=paged_kernel,
-                                   paged_interpret=paged_interpret)
+                                   paged_kernel=paged_kernel)
 
     if cfg.scan_layers:
         x, aux, new_stack = _scan_layers(params["layers"], cfg, x, layer_fn,
@@ -161,8 +157,7 @@ def lm_specs(cfg) -> dict:
 def lm_apply(params, cfg, tokens, positions=None, prefix_embeds=None,
              caches=None, window: int = 0, use_flash: bool = False,
              moe_dense_ref: bool = False, kv_valid=None, return_hidden=False,
-             last_token_only=False, paged_kernel: bool = False,
-             paged_interpret=None):
+             last_token_only=False, paged_kernel: bool = False):
     """Decoder LM forward.  Returns (logits, aux, new_caches[, hidden]).
 
     ``last_token_only`` unembeds just the final position (serving prefill:
@@ -178,7 +173,7 @@ def lm_apply(params, cfg, tokens, positions=None, prefix_embeds=None,
     x, aux, new_caches = decoder_stack_apply(
         params, cfg, x, positions, caches=caches, window=window,
         use_flash=use_flash, moe_dense_ref=moe_dense_ref, kv_valid=kv_valid,
-        paged_kernel=paged_kernel, paged_interpret=paged_interpret)
+        paged_kernel=paged_kernel)
     if last_token_only:
         x = x[:, -1:]
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
@@ -273,8 +268,8 @@ def _lora_adjusted(shared_attn: dict, lora_g: dict) -> dict:
 
 def zamba_apply(params, cfg, tokens, positions=None, caches=None,
                 window: int = 0, use_flash: bool = False, use_kernel: bool = False,
-                kv_valid=None, last_token_only=False, paged_kernel: bool = False,
-                paged_interpret=None, **_):
+                kv_valid=None, last_token_only=False,
+                paged_kernel: bool = False, **_):
     x = L.embed_tokens(params["embedding"], cfg, tokens)
     B, S, _ = x.shape
     if positions is None:
@@ -321,8 +316,7 @@ def zamba_apply(params, cfg, tokens, positions=None, caches=None,
         h, new_attn_cache = attn.attention_apply(
             shared_attn, cfg, L.rmsnorm(shared["ln1"], x, cfg.norm_eps),
             positions, cache=attn_cache, window=window, use_flash=use_flash,
-            kv_valid=kv_valid, paged_kernel=paged_kernel,
-            paged_interpret=paged_interpret)
+            kv_valid=kv_valid, paged_kernel=paged_kernel)
         x = x + h
         x = x + L.mlp(shared["mlp"], L.rmsnorm(shared["ln2"], x, cfg.norm_eps))
         new_cache = (None if cache_g is None
@@ -435,7 +429,7 @@ def encdec_cross_kv(params, cfg, enc_out):
 def encdec_decode_stack(params, cfg, tokens, cross_kv, positions=None,
                         caches=None, window: int = 0, use_flash: bool = False,
                         kv_valid=None, last_token_only=False,
-                        paged_kernel: bool = False, paged_interpret=None):
+                        paged_kernel: bool = False):
     x = L.embed_tokens(params["embedding"], cfg, tokens)
     B, S, _ = x.shape
     if positions is None:
@@ -448,8 +442,7 @@ def encdec_decode_stack(params, cfg, tokens, cross_kv, positions=None,
                                      L.rmsnorm(p_l["ln1"], x, cfg.norm_eps),
                                      positions, cache=cache_l, window=window,
                                      use_flash=use_flash, kv_valid=kv_valid,
-                                     paged_kernel=paged_kernel,
-                                     paged_interpret=paged_interpret)
+                                     paged_kernel=paged_kernel)
         x = x + h
         x = x + attn.cross_attention_apply(p_l["xattn"], cfg,
                                            L.rmsnorm(p_l["ln_x"], x, cfg.norm_eps),
@@ -484,8 +477,8 @@ def encdec_decode_stack(params, cfg, tokens, cross_kv, positions=None,
 
 def encdec_apply(params, cfg, tokens, prefix_embeds=None, positions=None,
                  caches=None, window: int = 0, use_flash: bool = False,
-                 kv_valid=None, last_token_only=False, paged_kernel: bool = False,
-                 paged_interpret=None, **_):
+                 kv_valid=None, last_token_only=False,
+                 paged_kernel: bool = False, **_):
     enc_out = encdec_encode(params, cfg, prefix_embeds, use_flash=use_flash)
     cross_kv = encdec_cross_kv(params, cfg, enc_out)
     return encdec_decode_stack(params, cfg, tokens, cross_kv,
@@ -493,5 +486,4 @@ def encdec_apply(params, cfg, tokens, prefix_embeds=None, positions=None,
                                window=window, use_flash=use_flash,
                                kv_valid=kv_valid,
                                last_token_only=last_token_only,
-                               paged_kernel=paged_kernel,
-                               paged_interpret=paged_interpret)
+                               paged_kernel=paged_kernel)

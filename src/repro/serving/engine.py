@@ -92,7 +92,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
+from repro import kernels, obs
 from repro.models import Model, PagedCache
 from repro.serving.prefix_index import RadixPrefixIndex
 
@@ -460,7 +460,6 @@ class GenerationEngine:
                  page_size: int = 16, num_blocks: int = 0,
                  kv_cache_dtype: str = "fp",
                  paged_kernel: Optional[bool] = None,
-                 paged_interpret: Optional[bool] = None,
                  prefill_chunk: int = 0,
                  prefix_sharing: bool = True):
         """``cache_mode="paged"`` allocates KV memory as ``num_blocks`` blocks
@@ -468,12 +467,14 @@ class GenerationEngine:
         ``max_len`` worth per row, i.e. the contiguous footprint — pass less
         to actually oversubscribe).  Requires window=0.
 
-        Paged decode hot-path knobs (forwarded to :class:`PagedCache`):
-        ``kv_cache_dtype`` "fp" (default, training-parity oracle) or "int8"
-        (quantized block pools, 2x effective pool capacity);
-        ``paged_kernel`` None = auto (Pallas block-table kernel on TPU, JAX
-        gather fallback elsewhere), True/False forces; ``paged_interpret``
-        overrides the kernel's interpret auto-detect.  ``prefill_chunk``
+        Paged decode hot-path knobs: ``kv_cache_dtype`` "fp" (default,
+        training-parity oracle) or "int8" (quantized block pools, 2x
+        effective pool capacity).  GQA paged decode runs the Pallas
+        block-table kernel on TPU, always: ``paged_kernel=False`` raises
+        there.  On the CPU it takes the JAX gather by default, the kernel's
+        parity oracle, and ``paged_kernel=True`` runs the kernel in
+        interpret mode.  MLA and SSM models have no paged decode kernel and
+        ignore the knob.  ``prefill_chunk``
         (0 = off; rounded up to the bucket size) streams long prompts
         through fixed-width compute chunks that write the paged pool
         incrementally, bounding prefill compile shapes at the chunk width.
@@ -506,21 +507,30 @@ class GenerationEngine:
         self.page_size = page_size
         self.num_blocks = num_blocks
         self.kv_cache_dtype = kv_cache_dtype
-        self.paged_interpret = paged_interpret
         self.prefix_sharing = bool(prefix_sharing) and cache_mode == "paged"
         self.prefill_chunk = _bucket(prefill_chunk) if prefill_chunk else 0
-        self._policy_knobs = dict(kv_dtype=kv_cache_dtype,
-                                  use_kernel=paged_kernel,
-                                  interpret=paged_interpret)
         # resolved once per engine: the jitted impls read it at trace time
-        self._use_paged_kernel = (
-            cache_mode == "paged"
-            and PagedCache(block_size=page_size, num_blocks=0,
-                           **self._policy_knobs).kernel_enabled())
-        self._prefill_jit = jax.jit(self._prefill_impl)
-        self._decode_jit = jax.jit(self._decode_impl)
-        self._loop_jit = jax.jit(self._decode_loop_impl,
+        self._use_paged_kernel = self._resolve_paged_kernel(paged_kernel)
+        # the cache (argument 1) is donated: every caller replaces
+        # ``session.cache`` with the returned one, so the pool is updated in
+        # place instead of living twice in device memory during each call
+        self._prefill_jit = jax.jit(self._prefill_impl, donate_argnums=1)
+        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=1)
+        self._loop_jit = jax.jit(self._decode_loop_impl, donate_argnums=1,
                                  static_argnames=("T", "per_row"))
+
+    def _resolve_paged_kernel(self, requested: Optional[bool]) -> bool:
+        cfg = self.model.cfg
+        gqa_paged = (self.cache_mode == "paged" and not cfg.uses_mla
+                     and not cfg.is_attention_free)
+        if not gqa_paged:
+            return False
+        on_cpu = kernels.interpret_mode()
+        if requested is False and not on_cpu:
+            raise ValueError(
+                "paged_kernel=False: on TPU the GQA paged decode always runs "
+                "the compiled Pallas kernel; the gather is a CPU oracle")
+        return (not on_cpu) if requested is None else bool(requested)
 
     # --------------------------------------------------------- weight store
     @property
@@ -675,8 +685,7 @@ class GenerationEngine:
         kw = {"cross_kv": cross_kv} if self.model.cfg.family == "encdec" else {}
         logits, new_cache = self.model.decode_step(
             params, tokens, positions, cache, window=self.window,
-            kv_valid=valid, paged_kernel=self._use_paged_kernel,
-            paged_interpret=self.paged_interpret, **kw)
+            kv_valid=valid, paged_kernel=self._use_paged_kernel, **kw)
         return logits, new_cache
 
     def _decode_impl(self, params, cache, tokens, positions, valid, cross_kv):
@@ -685,7 +694,7 @@ class GenerationEngine:
         logits, new_cache = self.model.decode_step(
             params, tokens, positions, cache, window=self.window,
             kv_valid=valid[:, None], paged_kernel=self._use_paged_kernel,
-            paged_interpret=self.paged_interpret, **kw)
+            **kw)
         return logits[:, 0, :], new_cache
 
     def _decode_loop_impl(self, params, cache, last_logits, lengths, stopped,
@@ -735,8 +744,7 @@ class GenerationEngine:
             logits, cache = self.model.decode_step(
                 params, feed, pos, cache, window=self.window,
                 kv_valid=accept[:, None],
-                paged_kernel=self._use_paged_kernel,
-                paged_interpret=self.paged_interpret, **kw)
+                paged_kernel=self._use_paged_kernel, **kw)
             last_logits = jnp.where(accept[:, None], logits[:, 0, :],
                                     last_logits)
             lengths = lengths + accept.astype(lengths.dtype)
@@ -769,7 +777,8 @@ class GenerationEngine:
             per_row = max(1, math.ceil(self.max_len / self.page_size))
             n_blocks = self.num_blocks or B * per_row
             policy = PagedCache(block_size=self.page_size,
-                                num_blocks=n_blocks, **self._policy_knobs)
+                                num_blocks=n_blocks,
+                                kv_dtype=self.kv_cache_dtype)
             prefix = (RadixPrefixIndex(self.page_size)
                       if self.prefix_sharing else None)
             allocator = BlockAllocator(n_blocks, self.page_size, B, per_row,

@@ -40,12 +40,23 @@ def test_collective_parser():
 
 def test_roofline_terms_dominance():
     t = roofline_terms(flops=197e12, bytes_accessed=819e9, coll_bytes=0,
-                       n_chips=1)
+                       n_chips=1, device_kind="TPU v5 lite")
     # exactly 1s compute, 1s memory, 0 collective
     assert t["t_compute_s"] == pytest.approx(1.0)
     assert t["t_memory_s"] == pytest.approx(1.0)
-    t2 = roofline_terms(1e12, 1e9, 1e12, n_chips=256)
+    t2 = roofline_terms(1e12, 1e9, 1e12, n_chips=256,
+                        device_kind="TPU v5 lite")
     assert t2["dominant"] == "collective"
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", ""])
+def test_unknown_device_kind_has_no_peaks(kind):
+    """A device with no published peaks is an error, not v5e by default."""
+    from repro.launch.hlo_stats import peaks
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks(kind)
+    with pytest.raises(KeyError):
+        roofline_terms(1.0, 1.0, 0, n_chips=1, device_kind=kind)
 
 
 @pytest.mark.slow
@@ -59,10 +70,10 @@ def test_small_mesh_lower_compile_subprocess():
         from repro.configs import get_config
         from repro.models import Model
         from repro.distributed.sharding import ShardingRules, use_sharding_rules
+        from repro.launch.mesh import make_debug_mesh
         from repro.launch.specs import batch_shardings
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"),
-                             devices=jax.devices()[:4])
+        mesh = make_debug_mesh(model=2, data=2)
         cfg = get_config("qwen3-32b").reduced(d_model=64, n_heads=4,
                                               n_kv_heads=2, head_dim=16,
                                               d_ff=128, vocab_size=256)

@@ -46,8 +46,7 @@ def test_engine_kernel_matches_contiguous(gqa_setup):
               temperature=1.0)
     contiguous = GenerationEngine(model, params, **kw)
     kernel = GenerationEngine(model, params, cache_mode="paged",
-                              page_size=16, paged_kernel=True,
-                              paged_interpret=True, **kw)
+                              page_size=16, paged_kernel=True, **kw)
     assert kernel._use_paged_kernel
     ctx = [tok.encode("kernel parity a"), tok.encode("b"),
            tok.encode("row three !")]
@@ -60,19 +59,32 @@ def test_engine_kernel_matches_contiguous(gqa_setup):
     np.testing.assert_array_equal(sc.lengths, sk.lengths)
 
 
-def test_kernel_auto_detect_off_tpu(gqa_setup):
-    """Default policy: the compiled kernel engages only on TPU backends; on
-    this CPU container auto-detect must fall back to the JAX gather path
-    (``paged_interpret`` / ``paged_kernel`` overrides stay available)."""
-    from repro.models.model import PagedCache
+def test_kernel_auto_detect_off_tpu(gqa_setup, monkeypatch):
+    """The backend alone picks the paged decode path.  On the CPU the JAX
+    gather is the default and ``paged_kernel=True`` runs the kernel in
+    interpret mode.  On a TPU the GQA paged engine always runs the compiled
+    kernel: asking for the gather raises, and so does asking for interpret
+    mode, which is no longer an option."""
+    from repro import kernels
     cfg, model, params, tok = gqa_setup
-    assert jax.default_backend() != "tpu"   # container invariant
-    assert not PagedCache(block_size=16, num_blocks=4).kernel_enabled()
-    assert PagedCache(block_size=16, num_blocks=4,
-                      use_kernel=True).kernel_enabled()
-    eng = GenerationEngine(model, params, pad_id=tok.pad_id, stop_ids=(),
-                           max_len=64, cache_mode="paged", page_size=16)
-    assert not eng._use_paged_kernel
+    kw = dict(pad_id=tok.pad_id, stop_ids=(), max_len=64,
+              cache_mode="paged", page_size=16)
+    assert kernels.interpret_mode()          # the tests run on the CPU
+    assert not GenerationEngine(model, params, **kw)._use_paged_kernel
+    assert GenerationEngine(model, params, paged_kernel=True,
+                            **kw)._use_paged_kernel
+    assert not GenerationEngine(model, params, max_len=64, pad_id=0,
+                                stop_ids=())._use_paged_kernel
+
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    assert GenerationEngine(model, params, **kw)._use_paged_kernel
+    with pytest.raises(ValueError, match="paged_kernel=False"):
+        GenerationEngine(model, params, paged_kernel=False, **kw)
+    with pytest.raises(TypeError, match="paged_interpret"):
+        GenerationEngine(model, params, paged_interpret=True, **kw)
+    mla = Model(get_config("deepseek-v2-236b").reduced())
+    eng = GenerationEngine(mla, mla.init(jax.random.PRNGKey(0)), **kw)
+    assert not eng._use_paged_kernel         # MLA's gather is its only path
 
 
 # ------------------------------------------------------------ int8 KV pools
@@ -108,8 +120,7 @@ def test_engine_int8_close_to_fp_oracle(gqa_setup, use_kernel):
               temperature=1.0, cache_mode="paged", page_size=16)
     fp = GenerationEngine(model, params, **kw)
     i8 = GenerationEngine(model, params, kv_cache_dtype="int8",
-                          paged_kernel=use_kernel, paged_interpret=True,
-                          **kw)
+                          paged_kernel=use_kernel, **kw)
     ctx = [tok.encode("int8 pools a"), tok.encode("longer row number two")]
     rk = jax.random.split(jax.random.PRNGKey(11), len(ctx))
     sf = fp.start([list(c) for c in ctx])

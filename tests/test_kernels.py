@@ -76,8 +76,8 @@ def _paged_setup(seed, B, H, Hk, D, Dv, N, bs, T, lengths):
     """Random pools + shuffled block assignment for the given row lengths."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    k_pool = jnp.asarray(rng.normal(size=(N, bs, Hk, D)), jnp.float32)
-    v_pool = jnp.asarray(rng.normal(size=(N, bs, Hk, Dv)), jnp.float32)
+    k_pool = jnp.asarray(rng.normal(size=(N, Hk, bs, D)), jnp.float32)
+    v_pool = jnp.asarray(rng.normal(size=(N, Hk, bs, Dv)), jnp.float32)
     table = np.full((B, T), -1, np.int32)
     free = list(rng.permutation(N - 1))          # last block = trash
     for b, n in enumerate(lengths):
@@ -186,8 +186,8 @@ def test_paged_attention_ref_matches_dense_attention():
     S = T * bs
     # pack each row's blocks back into a contiguous (B,S,...) layout
     ids = np.where(np.asarray(table) < 0, N - 1, np.asarray(table))
-    k_rows = np.asarray(k_pool)[ids].reshape(B, S, Hk, D)
-    v_rows = np.asarray(v_pool)[ids].reshape(B, S, Hk, D)
+    k_rows = np.asarray(k_pool)[ids].swapaxes(2, 3).reshape(B, S, Hk, D)
+    v_rows = np.asarray(v_pool)[ids].swapaxes(2, 3).reshape(B, S, Hk, D)
     out = R.paged_attention_ref(q, k_pool, v_pool, table, q_pos)
     for b, n in enumerate(lengths):
         qb = jnp.asarray(q)[b : b + 1, None]                 # (1,1,H,D)
