@@ -7,7 +7,8 @@ point of ``last_stats``.  This rule keeps it that way:
 * metric names registered on a ``counter`` / ``gauge`` / ``timer`` /
   ``histogram`` must parse: lowercase ``[a-z0-9_]`` segments, and a
   slashed name's namespace must be one of the known surfaces
-  (``rollout/``, ``tool/``, ``train/``, ``reward/``, ``engine/``, …) —
+  (``rollout/``, ``tool/``, ``train/``, ``reward/``, ``engine/``,
+  ``compile/``, …) —
   a typo'd namespace silently forks the metric off every dashboard;
 * a *bare* (unslashed) name is only meaningful on a child registry that
   forwards under a ``parent_prefix`` — modules that never construct one
@@ -30,7 +31,7 @@ from repro.analysis.rules.common import (dotted_name,
                                          iter_calls, str_arg)
 
 DEFAULT_NAMESPACES = ("rollout", "tool", "train", "reward", "engine",
-                      "eval", "dryrun")
+                      "eval", "dryrun", "compile")
 _SEGMENT_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 _INSTRUMENT_FACTORIES = {"counter", "gauge", "timer", "histogram"}
 _FINALIZERS = ("_finalize_stats",)

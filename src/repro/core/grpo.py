@@ -162,6 +162,9 @@ def make_grpo_train_step(model, opt_cfg, grpo_cfg: GRPOConfig,
     (params, opt_state, metrics)`` with optional microbatch grad accumulation.
 
     batch layout == Model.input_specs("train_4k") (+ optional prefix_embeds).
+    The step's ops carry the ``forward``, ``loss`` and ``optimizer`` name
+    scopes (their backward parts ``transpose(jvp(...))`` of the first two),
+    so a profile splits a step by them.
     """
     from repro.optim.adamw import adamw_update
 
@@ -169,12 +172,14 @@ def make_grpo_train_step(model, opt_cfg, grpo_cfg: GRPOConfig,
         fwd = {"tokens": mb["tokens"]}
         if "prefix_embeds" in mb:
             fwd["prefix_embeds"] = mb["prefix_embeds"]
-        logits, aux, _ = model.apply(params, fwd, use_flash=use_flash)
+        with jax.named_scope("forward"):
+            logits, aux, _ = model.apply(params, fwd, use_flash=use_flash)
         if "prefix_embeds" in mb and model.cfg.family == "vlm":
             # vlm: logits cover [prefix, text]; the RL loss is text-only
             logits = logits[:, mb["prefix_embeds"].shape[1]:, :]
-        return grpo_loss(logits, mb, grpo_cfg, aux=aux,
-                         use_fused=use_fused_logprob)
+        with jax.named_scope("loss"):
+            return grpo_loss(logits, mb, grpo_cfg, aux=aux,
+                             use_fused=use_fused_logprob)
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -217,8 +222,9 @@ def make_grpo_train_step(model, opt_cfg, grpo_cfg: GRPOConfig,
             metrics = jax.tree_util.tree_map(lambda m: m / k, msum)
         else:
             (loss, metrics), grads = grad_fn(params, batch)
-        params, opt_state, opt_metrics = adamw_update(opt_cfg, grads,
-                                                      opt_state, params)
+        with jax.named_scope("optimizer"):
+            params, opt_state, opt_metrics = adamw_update(opt_cfg, grads,
+                                                          opt_state, params)
         metrics.update(opt_metrics)
         return params, opt_state, metrics
 

@@ -167,7 +167,6 @@ class _StreamMetrics:
         self.reg = r = obs.MetricsRegistry(parent=obs.get().registry,
                                            parent_prefix="rollout/")
         self.rounds = r.counter("rounds")
-        self.gen_s = r.counter("gen_s")
         self.tool_wait = r.timer("tool_wait_s")
         self.tool_s = r.counter("tool_latency_s")
         self.tool_timeouts = r.counter("tool_timeouts")
@@ -564,7 +563,7 @@ class ContinuousScheduler:
         out = {
             "wall_s": wall,
             "rounds": m.rounds.value,
-            "gen_s": m.gen_s.value,
+            "gen_s": m.decode_round.sum,
             "tool_wait_s": m.tool_wait.sum,
             "refills": m.refills.value,
             "model_tokens": m.model_tokens.value,
@@ -603,8 +602,11 @@ class ContinuousScheduler:
 
     def _schedule(self, session, slots, queue, by_future, m, trc, retired,
                   retire, refill, preempt) -> Iterator[Trajectory]:
-        """The park/retire/refill loop proper (see module docstring)."""
-        turn_budget = self.config.max_new_tokens
+        """The park/retire/refill loop proper (see module docstring).  Each
+        round is one ``rollout/round`` span (retirees are yielded between
+        rounds, outside it), its phases the child spans ``rollout/refill``,
+        ``rollout/tools``, ``rollout/absorb``, ``rollout/decode`` and
+        ``rollout/parse``."""
         no_progress = 0
         profiling = _jax_profile_start()
         prof_rounds = 0
@@ -612,219 +614,247 @@ class ContinuousScheduler:
             for tr in retired:
                 yield tr
             retired.clear()
-            progress = refill() > 0
-            parked = [s for s in slots if s.state is SlotState.PARKED]
-            active = [s for s in slots if s.state is SlotState.ACTIVE]
-            if not parked and not active:
-                break
-            if parked:
-                # Overlap point: non-blocking drain while rows are decoding;
-                # block for the first completion only when nothing can decode.
-                # The drain is scoped to our own futures so several consumers
-                # can share one executor.
-                if by_future:
-                    if active:
-                        ready = self.executor.drain_ready(by_future)
-                    else:
-                        t0 = time.monotonic()
-                        ready = self.executor.wait_ready(futures=by_future)
-                        m.tool_wait.observe(time.monotonic() - t0)
-                    for fut in ready:
-                        target = by_future.pop(fut, None)
-                        if target is None:
-                            continue
-                        if isinstance(target, _Swapped):
-                            # row is swapped out: stage the observation on
-                            # the record; swap-in absorbs it (the max_len
-                            # check runs there, where lengths exist again)
-                            target.pending_obs = self._obs_ids(
-                                target.calls, fut, m)
-                            target.future = None
-                            progress = True
-                            continue
-                        self._land(session, target, fut, retire, m)
-                        progress = True
-                # Absorb landed observations whose rows can get cache blocks;
-                # the rest stay pending (paged backpressure) and retry once a
-                # retirement frees blocks.  ``claimed`` makes the per-row
-                # checks cumulative: several observations admitted into one
-                # batched prefill must not jointly over-commit the pool.
-                rows, obs_lists = [], []
-                claimed = 0
-                for slot in slots:
-                    if slot.state is not SlotState.PARKED \
-                            or slot.pending_obs is None:
-                        continue
-                    need = self._obs_blocks(session, slot)
-                    if need > self._free_after(session, claimed):
-                        continue
-                    claimed += need
-                    ids = slot.pending_obs
-                    tr = slot.job.traj
-                    tr.append(Role.OBSERVATION, ids)
-                    tr.meta["logprobs"].extend([0.0] * len(ids))
-                    tr.meta["policy_versions"].extend(
-                        [self._active_version()] * len(ids))
-                    rows.append(slot.row)
-                    obs_lists.append(ids)
-                    if trc.enabled:
-                        # park -> revived: the row's tool-I/O shadow
-                        trc.complete(f"slot{slot.row}", "tool_wait",
-                                     slot.park_t, trc.now(),
-                                     job=slot.job.index,
-                                     obs_tokens=len(ids))
-                    slot.pending_obs, slot.future, slot.calls = None, None, []
-                    slot.state = SlotState.ACTIVE
-                    progress = True
-                if rows:
-                    # one batched prefill for every observation that landed
-                    # this round (each row was checked to fit above)
-                    self._extend_rows(session, rows, obs_lists)
-                # absorption revives rows (and retire may refill slots):
-                # re-derive the active set so the parse loop below covers
-                # every row the engine will actually decode this round
+            with obs.span("rollout/round"):
+                with obs.span("rollout/refill"):
+                    progress = refill() > 0
+                parked = [s for s in slots if s.state is SlotState.PARKED]
                 active = [s for s in slots if s.state is SlotState.ACTIVE]
-                if not active:
-                    if not progress and not by_future:
-                        # pool wedged: every slot is waiting for blocks that
-                        # nothing left alive can free — swap out the longest
-                        self._preempt(session, slots, retire, preempt, m)
-                    continue
+                if not parked and not active:
+                    break
+                if parked:
+                    if by_future:
+                        with obs.span("rollout/tools"):
+                            progress |= self._collect_tools(
+                                session, by_future, bool(active), retire, m)
+                    with obs.span("rollout/absorb"):
+                        progress |= self._absorb(session, slots, trc)
+                    # absorption revives rows (and retire may refill
+                    # slots): re-derive the active set so the parse phase
+                    # covers every row the engine will actually decode
+                    active = [s for s in slots
+                              if s.state is SlotState.ACTIVE]
+                    if not active:
+                        if not progress and not by_future:
+                            # pool wedged: every slot is waiting for blocks
+                            # that nothing left alive can free — swap out
+                            # the longest
+                            self._preempt(session, slots, retire, preempt, m)
+                        continue
 
-            # Round boundary: swap to the latest published weights (if a
-            # learner staged any since the previous round).  The swap can
-            # only happen HERE — never inside a round — so every token this
-            # round samples is attributable to exactly one version.
-            ver = 0
-            if self._versioned:
-                prev_ver = int(self.engine.active_version)
-                ver = int(self.engine.refresh_weights())
-                if ver != prev_ver:
-                    m.weight_refreshes.add()
-                    if trc.enabled:
-                        trc.instant("sched", "weight_refresh", version=ver)
-
-            m.rounds.add()
-            m.slot_rounds.add(len(slots))
-            m.active_slot_rounds.add(len(active))
-            row_keys = self._row_keys(slots)
-            n_parked = sum(1 for s in slots if s.state is SlotState.PARKED)
-            round_budget = self._round_budget(len(active), n_parked)
-            gen_kw = {}
-            if self._supports_rounds:
-                offsets = np.zeros((len(slots),), np.int32)
-                budgets = np.zeros((len(slots),), np.int32)
-                for s in active:
-                    done = len(s.turn_toks)      # tokens already this turn
-                    offsets[s.row] = done
-                    budgets[s.row] = max(0, min(round_budget,
-                                                turn_budget - done))
-                gen_kw = {"step_offsets": offsets, "row_budgets": budgets}
-                if round_budget < turn_budget:
-                    m.adaptive_rounds.add()
-                m.min_round_budget.set_min(float(round_budget))
-            t_round = trc.now() if trc.enabled else 0.0
-            t0 = time.monotonic()
-            res = self.engine.generate(
-                session, round_budget, None,
-                temperature=self.config.temperature, row_keys=row_keys,
-                **gen_kw)
-            dt_round = time.monotonic() - t0
-            m.gen_s.add(dt_round)
-            m.decode_round.observe(dt_round)
-            if trc.enabled:
-                t1_round = trc.now()
-                for s in active:
-                    trc.complete(f"slot{s.row}", "decode_round",
-                                 t_round, t1_round, turn=s.turn_idx,
-                                 job=s.job.index)
-            if profiling:
-                prof_rounds += 1
-                if prof_rounds >= _JAX_PROFILE_ROUNDS:
-                    _jax_profile_stop()
-                    profiling = False
-            if hasattr(self.engine, "cache_utilization"):
-                util = self.engine.cache_utilization(session)
-                if util is not None:
-                    m.cache_util.observe(util)
-
-            stop_set = set(getattr(self.engine, "stop_ids", ()) or ())
-            for slot in active:
-                n_tok = int(res.counts[slot.row])
-                if n_tok == 0 and not slot.turn_toks:
-                    if np.asarray(session.stopped)[slot.row]:
-                        # the engine refused the row: context exhausted
-                        retire(slot, "max_len", finished=False)
-                    else:
-                        # paged pool starvation: no blocks for this round —
-                        # stay ACTIVE and retry once a retirement frees some
-                        m.starved_rounds.add()
-                    continue
-                if n_tok:
-                    slot.turn_toks.extend(res.tokens[slot.row, :n_tok]
-                                          .tolist())
-                    slot.turn_lps.extend(
-                        float(x) for x in res.logprobs[slot.row, :n_tok])
-                    slot.turn_vers.extend([ver] * n_tok)
-                    if self._versioned and ver not in slot.job.versions:
-                        # pin the sampling version until this trajectory
-                        # retires (its old_logprobs reference these params)
-                        self.engine.pin_version(ver)
-                        slot.job.versions.add(ver)
-                    progress = True
-                # A logical turn ends on a stop id, the full turn budget, or
-                # an exhausted context; otherwise the row stays mid-turn and
-                # resumes next round (round-sliced turns).
-                turn_done = (not self._supports_rounds
-                             or slot.turn_toks[-1] in stop_set
-                             or len(slot.turn_toks) >= turn_budget
-                             or bool(np.asarray(session.stopped)[slot.row]))
-                if not turn_done:
-                    continue
-                row_toks = slot.turn_toks
-                tr = slot.job.traj
-                tr.append(Role.MODEL, row_toks)
-                tr.meta["logprobs"].extend(slot.turn_lps)
-                tr.meta["policy_versions"].extend(slot.turn_vers)
-                tr.meta["turn_versions"].append(slot.turn_vers[-1])
-                m.model_tokens.add(len(row_toks))
-                slot.turn_toks, slot.turn_lps = [], []
-                slot.turn_vers = []
-                slot.turn_idx += 1
-                text = self.tok.decode(row_toks)
-                calls, answer = self.env.manager.parse_response(text)
-                over_budget = (tr.n_tool_calls + len(calls)
-                               > self.env.max_tool_calls)
-                if answer is not None or not calls or over_budget:
-                    reason = ("answer" if answer is not None else
-                              "no_call" if not calls else "tool_budget")
-                    retire(slot, reason, finished=answer is not None)
-                    continue
-                tr.n_tool_calls += len(calls)
-                if slot.turn_idx >= self.config.max_turns:
-                    # calls counted but not executed — same contract as the
-                    # reference loop, which breaks before its Invoke stage
-                    retire(slot, "max_turns", finished=False)
-                    continue
-                slot.calls = calls
-                slot.future = self.executor.submit(calls)
-                by_future[slot.future] = slot
-                slot.state = SlotState.PARKED
+                ver = self._refresh_weights(m, trc)
+                m.rounds.add()
+                m.slot_rounds.add(len(slots))
+                m.active_slot_rounds.add(len(active))
+                row_keys = self._row_keys(slots)
+                n_parked = sum(1 for s in slots
+                               if s.state is SlotState.PARKED)
+                round_budget = self._round_budget(len(active), n_parked)
+                gen_kw = self._round_controls(slots, active, round_budget, m)
+                with obs.span("rollout/decode", rows=len(active)) as dec:
+                    res = self.engine.generate(
+                        session, round_budget, None,
+                        temperature=self.config.temperature,
+                        row_keys=row_keys, **gen_kw)
+                m.decode_round.observe(dec.seconds)
                 if trc.enabled:
-                    slot.park_t = trc.now()
-                session.stopped[slot.row] = True
+                    for s in active:
+                        trc.complete(f"slot{s.row}", "decode_round",
+                                     trc.since(dec.t0), trc.since(dec.t1),
+                                     turn=s.turn_idx, job=s.job.index)
+                if profiling:
+                    prof_rounds += 1
+                    if prof_rounds >= _JAX_PROFILE_ROUNDS:
+                        _jax_profile_stop()
+                        profiling = False
+                if hasattr(self.engine, "cache_utilization"):
+                    util = self.engine.cache_utilization(session)
+                    if util is not None:
+                        m.cache_util.observe(util)
+                with obs.span("rollout/parse"):
+                    progress |= self._parse(session, active, res, ver,
+                                            by_future, retire, m, trc)
 
-            # Wedge breaker: rounds that move no token, land no future and
-            # admit nothing — with no tool I/O left in flight — mean every
-            # occupied row is starved for blocks that nothing alive can
-            # free.  Swap out the longest row (vLLM-preemption analogue).
-            if progress or retired or by_future:
-                no_progress = 0
-            else:
-                no_progress += 1
-                if no_progress >= 2:
-                    self._preempt(session, slots, retire, preempt, m)
+                # Wedge breaker: rounds that move no token, land no future
+                # and admit nothing (no tool I/O left in flight) mean every
+                # occupied row is starved for blocks that nothing alive can
+                # free.  Swap out the longest row (vLLM-preemption analogue).
+                if progress or retired or by_future:
                     no_progress = 0
+                else:
+                    no_progress += 1
+                    if no_progress >= 2:
+                        self._preempt(session, slots, retire, preempt, m)
+                        no_progress = 0
+
+    def _collect_tools(self, session, by_future, any_active: bool, retire,
+                       m) -> bool:
+        """Overlap point: a non-blocking drain while rows are decoding, a
+        blocking wait for the first completion only when nothing can
+        decode.  The drain is scoped to our own futures so several
+        consumers can share one executor.  Landed results become pending
+        observations (or retire their row).  True when anything landed."""
+        if any_active:
+            ready = self.executor.drain_ready(by_future)
+        else:
+            t0 = time.monotonic()
+            ready = self.executor.wait_ready(futures=by_future)
+            m.tool_wait.observe(time.monotonic() - t0)
+        landed = False
+        for fut in ready:
+            target = by_future.pop(fut, None)
+            if target is None:
+                continue
+            landed = True
+            if isinstance(target, _Swapped):
+                # row is swapped out: stage the observation on the record;
+                # swap-in absorbs it (the max_len check runs there, where
+                # lengths exist again)
+                target.pending_obs = self._obs_ids(target.calls, fut, m)
+                target.future = None
+                continue
+            self._land(session, target, fut, retire, m)
+        return landed
+
+    def _absorb(self, session, slots, trc) -> bool:
+        """Absorb landed observations whose rows can get cache blocks, in
+        one batched prefill; the rest stay pending (paged backpressure) and
+        retry once a retirement frees blocks.  ``claimed`` makes the
+        per-row checks cumulative: several observations admitted into one
+        batched prefill must not jointly over-commit the pool.  True when
+        any row was revived."""
+        rows, obs_lists = [], []
+        claimed = 0
+        for slot in slots:
+            if slot.state is not SlotState.PARKED \
+                    or slot.pending_obs is None:
+                continue
+            need = self._obs_blocks(session, slot)
+            if need > self._free_after(session, claimed):
+                continue
+            claimed += need
+            ids = slot.pending_obs
+            tr = slot.job.traj
+            tr.append(Role.OBSERVATION, ids)
+            tr.meta["logprobs"].extend([0.0] * len(ids))
+            tr.meta["policy_versions"].extend(
+                [self._active_version()] * len(ids))
+            rows.append(slot.row)
+            obs_lists.append(ids)
+            if trc.enabled:
+                # park -> revived: the row's tool-I/O shadow
+                trc.complete(f"slot{slot.row}", "tool_wait", slot.park_t,
+                             trc.now(), job=slot.job.index,
+                             obs_tokens=len(ids))
+            slot.pending_obs, slot.future, slot.calls = None, None, []
+            slot.state = SlotState.ACTIVE
+        if rows:
+            self._extend_rows(session, rows, obs_lists)
+        return bool(rows)
+
+    def _refresh_weights(self, m, trc) -> int:
+        """Round boundary: swap to the latest published weights (if a
+        learner staged any since the previous round).  The swap can only
+        happen HERE — never inside a round — so every token this round
+        samples is attributable to exactly one version.  Returns it."""
+        if not self._versioned:
+            return 0
+        prev_ver = int(self.engine.active_version)
+        ver = int(self.engine.refresh_weights())
+        if ver != prev_ver:
+            m.weight_refreshes.add()
+            if trc.enabled:
+                trc.instant("sched", "weight_refresh", version=ver)
+        return ver
+
+    def _round_controls(self, slots, active, round_budget: int, m) -> dict:
+        """The engine's round-slicing controls: each active row's offset
+        into its turn's sampling stream and its budget this round."""
+        if not self._supports_rounds:
+            return {}
+        turn_budget = self.config.max_new_tokens
+        offsets = np.zeros((len(slots),), np.int32)
+        budgets = np.zeros((len(slots),), np.int32)
+        for s in active:
+            done = len(s.turn_toks)      # tokens already this turn
+            offsets[s.row] = done
+            budgets[s.row] = max(0, min(round_budget, turn_budget - done))
+        if round_budget < turn_budget:
+            m.adaptive_rounds.add()
+        m.min_round_budget.set_min(float(round_budget))
+        return {"step_offsets": offsets, "row_budgets": budgets}
+
+    def _parse(self, session, active, res, ver: int, by_future, retire,
+               m, trc) -> bool:
+        """Fold each active row's round tokens into its turn; parse every
+        finished turn and retire the row, or submit its tool calls and park
+        it.  True when any row sampled a token."""
+        turn_budget = self.config.max_new_tokens
+        stop_set = set(getattr(self.engine, "stop_ids", ()) or ())
+        progress = False
+        for slot in active:
+            n_tok = int(res.counts[slot.row])
+            if n_tok == 0 and not slot.turn_toks:
+                if np.asarray(session.stopped)[slot.row]:
+                    # the engine refused the row: context exhausted
+                    retire(slot, "max_len", finished=False)
+                else:
+                    # paged pool starvation: no blocks for this round — stay
+                    # ACTIVE and retry once a retirement frees some
+                    m.starved_rounds.add()
+                continue
+            if n_tok:
+                slot.turn_toks.extend(res.tokens[slot.row, :n_tok].tolist())
+                slot.turn_lps.extend(
+                    float(x) for x in res.logprobs[slot.row, :n_tok])
+                slot.turn_vers.extend([ver] * n_tok)
+                if self._versioned and ver not in slot.job.versions:
+                    # pin the sampling version until this trajectory
+                    # retires (its old_logprobs reference these params)
+                    self.engine.pin_version(ver)
+                    slot.job.versions.add(ver)
+                progress = True
+            # A logical turn ends on a stop id, the full turn budget, or an
+            # exhausted context; otherwise the row stays mid-turn and
+            # resumes next round (round-sliced turns).
+            turn_done = (not self._supports_rounds
+                         or slot.turn_toks[-1] in stop_set
+                         or len(slot.turn_toks) >= turn_budget
+                         or bool(np.asarray(session.stopped)[slot.row]))
+            if not turn_done:
+                continue
+            row_toks = slot.turn_toks
+            tr = slot.job.traj
+            tr.append(Role.MODEL, row_toks)
+            tr.meta["logprobs"].extend(slot.turn_lps)
+            tr.meta["policy_versions"].extend(slot.turn_vers)
+            tr.meta["turn_versions"].append(slot.turn_vers[-1])
+            m.model_tokens.add(len(row_toks))
+            slot.turn_toks, slot.turn_lps = [], []
+            slot.turn_vers = []
+            slot.turn_idx += 1
+            text = self.tok.decode(row_toks)
+            calls, answer = self.env.manager.parse_response(text)
+            over_budget = (tr.n_tool_calls + len(calls)
+                           > self.env.max_tool_calls)
+            if answer is not None or not calls or over_budget:
+                reason = ("answer" if answer is not None else
+                          "no_call" if not calls else "tool_budget")
+                retire(slot, reason, finished=answer is not None)
+                continue
+            tr.n_tool_calls += len(calls)
+            if slot.turn_idx >= self.config.max_turns:
+                # calls counted but not executed — same contract as the
+                # reference loop, which breaks before its Invoke stage
+                retire(slot, "max_turns", finished=False)
+                continue
+            slot.calls = calls
+            slot.future = self.executor.submit(calls)
+            by_future[slot.future] = slot
+            slot.state = SlotState.PARKED
+            if trc.enabled:
+                slot.park_t = trc.now()
+            session.stopped[slot.row] = True
+        return progress
 
     # ------------------------------------------------------------- internals
     def _active_version(self) -> int:

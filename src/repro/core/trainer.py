@@ -107,13 +107,10 @@ class RolloutProducer:
         for tr in self.worker.rollout_stream(tasks, key,
                                              group_size=self.group_size):
             if streaming:
-                t_sc = o.tracer.now() if o.tracing else 0.0
-                with o.registry.timer("reward/score_s").time():
+                with obs.span("reward/score",
+                              job=tr.meta.get("job_index", -1)) as sp:
                     self.rewards.score_one(tr, tr.meta["ground_truth"])
-                if o.tracing:
-                    o.tracer.complete("reward", "score", t_sc,
-                                      o.tracer.now(),
-                                      job=tr.meta.get("job_index", -1))
+                o.registry.timer("reward/score_s").observe(sp.seconds)
             self.n_emitted += 1
             yield tr
         if streaming:
@@ -143,9 +140,10 @@ class Learner:
         # the optimizer state (argument 1) belongs to this learner alone and
         # is donated: its two f32 moments are 8 bytes per parameter, which a
         # step would otherwise hold twice.  The params are not donated —
-        # the engine's WeightStore may still be serving them.
-        self._train_step = jax.jit(make_grpo_train_step(
-            model, opt_cfg, grpo_cfg), donate_argnums=1)
+        # the engine's WeightStore may still be serving them.  The program
+        # is named ``train_step`` (HLO module ``jit_train_step``).
+        self._train_step = jax.jit(obs.named_program(make_grpo_train_step(
+            model, opt_cfg, grpo_cfg), "train_step"), donate_argnums=1)
         self._ref_logprob_fn = jax.jit(self._ref_logprobs_impl)
         self.n_updates = 0
         # masked per-token version lag of the last micro-batch (host copy,
@@ -204,17 +202,19 @@ class Learner:
 
         Publishes the refreshed params into the engine's weight store
         (staged — the rollout side swaps at its next round boundary).
-        Returns ``(metrics, n_model_tokens)``.
+        Returns ``(metrics, n_model_tokens)``; the metrics are ready on the
+        device, so ``train/update_s`` and the ``train/step`` span time the
+        step to its end, not its dispatch (callers read them as floats next
+        anyway).
         """
-        batch, batch_np = self.make_batch(trajs, adv)
-        o = obs.get()
-        t_up = o.tracer.now() if o.tracing else 0.0
-        with o.registry.timer("train/update_s").time():
+        with obs.span("train/make_batch", track="learner"):
+            batch, batch_np = self.make_batch(trajs, adv)
+        with obs.span("train/step", track="learner", event="learner_update",
+                      n_trajs=len(trajs)) as sp:
             self.params, self.opt_state, metrics = self._train_step(
                 self.params, self.opt_state, batch)
-        if o.tracing:
-            o.tracer.complete("learner", "learner_update", t_up,
-                              o.tracer.now(), n_trajs=len(trajs))
+            metrics = jax.block_until_ready(metrics)
+        obs.get().registry.timer("train/update_s").observe(sp.seconds)
         self.n_updates += 1
         if publish and self.engine is not None:
             if hasattr(self.engine, "publish"):

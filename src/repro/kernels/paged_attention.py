@@ -172,5 +172,6 @@ def paged_attention_fwd(q, k_pool, v_pool, table, q_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hk, G, Dv), q.dtype),
         interpret=interpret,
+        name="paged_attention",     # the kernel's name in a profile
     )(table, q_pos.astype(jnp.int32), *operands)
     return out.reshape(B, H, Dv)

@@ -16,8 +16,14 @@ or from the environment before launch::
     REPRO_TRACE_DIR=results/trace python examples/train_tool_agent.py
 
 Tests use :func:`scoped` to swap in an isolated bundle for one block.
-``REPRO_JAX_PROFILE=<dir>`` additionally wraps the first traced scheduler
-rounds in ``jax.profiler`` (handled in core/scheduler.py, not here).
+
+Host work is timed with :func:`span` (``obs/spans.py``): a
+``jax.profiler.TraceAnnotation`` that any profiler session records on the
+device trace's clock, and a complete event of the tracer when it is on.
+Importing this package registers the process's compile listener, which
+keeps the ``compile/*`` counters.  ``REPRO_JAX_PROFILE=<dir>``
+additionally wraps the first traced scheduler rounds in ``jax.profiler``
+(handled in core/scheduler.py, not here).
 """
 from __future__ import annotations
 
@@ -34,6 +40,7 @@ __all__ = [
     "NULL_REGISTRY", "TIME_BUCKETS", "VALUE_BUCKETS",
     "SpanTracer", "NullTracer", "NULL_TRACER", "validate_chrome_trace",
     "Observability", "get", "configure", "scoped",
+    "span", "current_span", "named_program",
 ]
 
 
@@ -89,3 +96,8 @@ def scoped(metrics: bool = True, trace: bool = False,
         yield bundle
     finally:
         _current = prev
+
+
+# imported last: spans.py reads ``get`` from this module at call time, and
+# registers the process's compile listener as it is imported
+from .spans import current_span, named_program, span  # noqa: E402

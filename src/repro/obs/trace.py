@@ -13,7 +13,12 @@ viewer shows "slot 0", "slot 1", ... "tools", "learner" as separate rows.
 Timestamps come from one shared ``time.monotonic()`` epoch per tracer, so
 spans recorded from the scheduler thread and the background tool loop
 line up on the same timeline.  Everything is microseconds (the Chrome
-format's unit) and clamped non-negative.
+format's unit) and clamped non-negative.  The export's ``otherData``
+carries the epoch's wall-clock nanoseconds (``epoch_unix_ns``), the clock
+a ``jax.profiler`` trace keeps: an event at ``ts`` happened at
+``epoch_unix_ns + 1000 * ts``, and in a profile whose ``Task Environment``
+plane reads ``profile_start_time`` its xplane ``start_ns`` is that less
+``profile_start_time`` — one offset maps the export onto the device trace.
 
 :class:`NullTracer` is the disabled twin: every method is a no-op,
 ``now()`` is a constant, ``export()`` writes nothing.  Call sites branch
@@ -43,6 +48,7 @@ class SpanTracer:
         self.out_dir = out_dir
         self.pid = pid
         self._epoch = time.monotonic()
+        self.epoch_unix_ns = time.time_ns()
         self._events: Deque[dict] = collections.deque(maxlen=self.max_events)
         self._tracks: Dict[str, int] = {}
         self._lock = threading.Lock()
@@ -52,6 +58,10 @@ class SpanTracer:
     def now(self) -> float:
         """Seconds since this tracer's epoch (pass to ``complete``)."""
         return time.monotonic() - self._epoch
+
+    def since(self, t_monotonic: float) -> float:
+        """A ``time.monotonic()`` reading in this tracer's epoch seconds."""
+        return t_monotonic - self._epoch
 
     def track_id(self, track: str) -> int:
         tid = self._tracks.get(track)
@@ -98,7 +108,9 @@ class SpanTracer:
         if self.out_dir is None or not self._events:
             return ""
         obj = {"traceEvents": self.events(),
-               "displayTimeUnit": "ms"}
+               "displayTimeUnit": "ms",
+               "otherData": {"clock": "monotonic",
+                             "epoch_unix_ns": self.epoch_unix_ns}}
         os.makedirs(self.out_dir, exist_ok=True)
         with self._lock:
             self._n_exports += 1
@@ -116,9 +128,13 @@ class NullTracer:
     lookup and a call."""
 
     enabled = False
+    epoch_unix_ns = 0
     __slots__ = ()
 
     def now(self) -> float:
+        return 0.0
+
+    def since(self, t_monotonic: float) -> float:
         return 0.0
 
     def track_id(self, track: str) -> int:

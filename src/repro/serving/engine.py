@@ -513,11 +513,18 @@ class GenerationEngine:
         self._use_paged_kernel = self._resolve_paged_kernel(paged_kernel)
         # the cache (argument 1) is donated: every caller replaces
         # ``session.cache`` with the returned one, so the pool is updated in
-        # place instead of living twice in device memory during each call
-        self._prefill_jit = jax.jit(self._prefill_impl, donate_argnums=1)
-        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=1)
-        self._loop_jit = jax.jit(self._decode_loop_impl, donate_argnums=1,
-                                 static_argnames=("T", "per_row"))
+        # place instead of living twice in device memory during each call.
+        # The programs carry fixed names (HLO modules ``jit_prefill``,
+        # ``jit_decode_step``, ``jit_decode_loop``): profiles are read by them
+        self._prefill_jit = jax.jit(
+            obs.named_program(self._prefill_impl, "prefill"),
+            donate_argnums=1)
+        self._decode_jit = jax.jit(
+            obs.named_program(self._decode_impl, "decode_step"),
+            donate_argnums=1)
+        self._loop_jit = jax.jit(
+            obs.named_program(self._decode_loop_impl, "decode_loop"),
+            donate_argnums=1, static_argnames=("T", "per_row"))
 
     def _resolve_paged_kernel(self, requested: Optional[bool]) -> bool:
         cfg = self.model.cfg
@@ -661,12 +668,10 @@ class GenerationEngine:
                             (len(prompt_ids) - 1) // a.block_size)
         return sum(1 for b in ids if a.refcount[b] >= 1)
 
-    def _sync_tables(self, session: DecodeSession) -> None:
-        """Push the host block table into the device cache, but only when
-        the allocator actually changed it — in the steady decode state
-        (every row's capacity already covers its budget) this is a no-op.
-        Blocks the radix index evicted since the last sync are pos-reset
-        here (their slabs hold stale K/V a future occupant must not see)."""
+    def _clear_evicted(self, session: DecodeSession) -> None:
+        """Pos-reset the blocks the radix index evicted since the last call:
+        their slabs hold stale K/V a future occupant must not see.  Runs
+        before anything writes into them."""
         a = session.allocator
         if a.pending_clear:
             blocks, a.pending_clear = a.pending_clear, []
@@ -674,11 +679,21 @@ class GenerationEngine:
                 session.cache, np.zeros((0,), np.int64), self.max_len,
                 self.window, policy=session.cache_policy,
                 freed_blocks=blocks)
-        if not a.dirty:
+
+    def _sync_tables(self, session: DecodeSession) -> None:
+        """Push the host block table into the device cache, but only when
+        the allocator actually changed it — in the steady decode state
+        (every row's capacity already covers its budget) this is a no-op.
+        Evicted blocks still pending are pos-reset first."""
+        a = session.allocator
+        if not a.pending_clear and not a.dirty:
             return
-        session.cache = session.cache_policy.set_tables(
-            session.cache, a.table)
-        a.dirty = False
+        with obs.span("engine/sync_tables"):
+            self._clear_evicted(session)
+            if a.dirty:
+                session.cache = session.cache_policy.set_tables(
+                    session.cache, a.table)
+                a.dirty = False
 
     # ------------------------------------------------------------- impl fns
     def _prefill_impl(self, params, cache, tokens, positions, valid, cross_kv):
@@ -765,6 +780,10 @@ class GenerationEngine:
 
     # ------------------------------------------------------------- session ops
     def start(self, contexts: List[List[int]], prefix_embeds=None) -> DecodeSession:
+        with obs.span("engine/start", rows=len(contexts)):
+            return self._start(contexts, prefix_embeds)
+
+    def _start(self, contexts, prefix_embeds) -> DecodeSession:
         B = len(contexts)
         cross_kv = None
         if self.model.cfg.family == "encdec":
@@ -826,17 +845,23 @@ class GenerationEngine:
                 f"context overflow: extend to {(session.lengths + lens).max()} "
                 f"tokens > engine max_len={self.max_len}; raise max_len or "
                 f"shorten prompts")
-        work, shared = self._share_prefixes(session, new_tokens)
-        wmax = max((len(t) for t in work), default=0)
-        C = self.prefill_chunk
-        if C and wmax > C:
-            for c0 in range(0, wmax, C):
-                self._extend_once(session,
-                                  [list(t[c0:c0 + C]) for t in work])
-        elif wmax:
-            self._extend_once(session, work)
-        if shared is not None:
-            self._finish_sharing(session, shared)
+        n_tok = int(lens.sum())
+        obs.get().registry.counter("engine/prefill_tokens").add(n_tok)
+        with obs.span("engine/extend", tokens=n_tok,
+                      rows=int((lens > 0).sum())):
+            with obs.span("engine/share"):
+                work, shared = self._share_prefixes(session, new_tokens)
+            wmax = max((len(t) for t in work), default=0)
+            C = self.prefill_chunk
+            if C and wmax > C:
+                for c0 in range(0, wmax, C):
+                    self._extend_once(session,
+                                      [list(t[c0:c0 + C]) for t in work])
+            elif wmax:
+                self._extend_once(session, work)
+            if shared is not None:
+                with obs.span("engine/share"):
+                    self._finish_sharing(session, shared)
 
     def _share_prefixes(self, session: DecodeSession,
                         new_tokens: List[List[int]]):
@@ -856,6 +881,8 @@ class GenerationEngine:
         if a is None or a.prefix is None:
             return new_tokens, None
         bs = a.block_size
+        o = obs.get()
+        shared_tokens = o.registry.counter("engine/shared_prefix_tokens")
         work = [list(t) for t in new_tokens]
         leaders: dict = {}          # prompt tuple -> leader row
         followers: List[Tuple[int, int, int]] = []   # (row, leader, n_tok)
@@ -873,6 +900,7 @@ class GenerationEngine:
                 followers.append((i, lead, len(t)))
                 work[i] = []
                 a.shared_tokens += len(t)
+                shared_tokens.add(len(t))
                 continue
             leaders[key] = i
             hit = a.prefix.lookup(t, (len(t) - 1) // bs)
@@ -881,8 +909,8 @@ class GenerationEngine:
                 n_hit = len(hit) * bs
                 session.lengths[i] += n_hit
                 a.shared_tokens += n_hit
+                shared_tokens.add(n_hit)
                 work[i] = list(t[n_hit:])
-                o = obs.get()
                 o.registry.counter("engine/radix_hits").add()
                 o.registry.counter("engine/radix_hit_tokens").add(n_hit)
                 if o.tracing:
@@ -923,77 +951,87 @@ class GenerationEngine:
         if a.dirty:
             self._sync_tables(session)
 
-    def _cow_range(self, session: DecodeSession, row: int, start: int,
-                   end: int) -> bool:
+    def _cow_plan(self, session: DecodeSession, row: int, start: int,
+                  end: int, copies: List[Tuple[int, int, int]]) -> bool:
         """Host-side copy-on-write barrier: before any device write to
         positions ``[start, end)`` of ``row``, replace each block in that
         range the row shares with other rows (refcount > 1) by a private
-        copy — allocate, slab-copy the K/V device-side, remap the row's
-        table slot.  Radix-indexed *full* blocks never appear in a write
-        range (writes start at the row's length, past every full block), so
-        only group-shared partial tails ever copy.  Returns False when the
-        pool cannot supply a replacement (caller backpressures; completed
-        copies stay valid)."""
+        one — allocate and remap the row's table slot, appending
+        ``(row, src, dst)`` to ``copies`` for :meth:`_cow_copy`, which
+        slab-copies the K/V device-side before the table sync.  Radix-indexed
+        *full* blocks never appear in a write range (writes start at the
+        row's length, past every full block), so only group-shared partial
+        tails ever copy.  Returns False when the pool cannot supply a
+        replacement (caller backpressures; planned copies stay valid)."""
         a = session.allocator
         if a is None or end <= start:
             return True
         bs = a.block_size
         b1 = min((end - 1) // bs, int(a.n_blocks[row]) - 1)
-        src: List[int] = []
-        dst: List[int] = []
-        ok = True
         for bi in range(start // bs, b1 + 1):
             if a.refcount[int(a.table[row, bi])] > 1:
                 s, d = a.cow(row, bi)
                 if d < 0:
-                    ok = False
-                    break
-                src.append(s)
-                dst.append(d)
-        if src:
+                    return False
+                copies.append((row, s, d))
+        return True
+
+    def _cow_copy(self, session: DecodeSession,
+                  copies: List[Tuple[int, int, int]]) -> None:
+        """The device half of copy-on-write: every planned block copy of
+        one engine call, in one pool update."""
+        if not copies:
+            return
+        # (the tracer's ``cow`` name belongs to the per-row instants below,
+        # which trace_check reads)
+        with obs.span("engine/cow", event="cow_copy", blocks=len(copies)):
+            # a copy may land in a block the radix just evicted: reset it
+            # first, or the reset that follows would erase the copy
+            self._clear_evicted(session)
             session.cache = self.model.copy_cache_blocks(
-                session.cache, src, dst, policy=session.cache_policy)
-            o = obs.get()
-            o.registry.counter("engine/cow_copies").add(len(src))
-            if o.tracing:
-                o.tracer.instant("cache", "cow", row=row, blocks=len(src))
-        return ok
+                session.cache, [c[1] for c in copies],
+                [c[2] for c in copies], policy=session.cache_policy)
+        o = obs.get()
+        o.registry.counter("engine/cow_copies").add(len(copies))
+        if o.tracing:
+            for row in sorted({c[0] for c in copies}):
+                o.tracer.instant("cache", "cow", row=row,
+                                 blocks=sum(c[0] == row for c in copies))
 
     def _extend_once(self, session: DecodeSession,
                      new_tokens: List[List[int]]) -> None:
         """One bucketed prefill call (a whole extend, or one chunk of it)."""
-        B = session.batch
         lens = np.array([len(t) for t in new_tokens], np.int64)
         if lens.max(initial=0) == 0:
             return
-        o = obs.get()
-        t_pre = o.tracer.now() if o.tracing else 0.0
-        with o.registry.timer("engine/prefill_s").time():
-            self._extend_inner(session, new_tokens, lens)
-        if o.tracing:
-            o.tracer.complete("engine", "prefill", t_pre, o.tracer.now(),
-                              tokens=int(lens.sum()),
-                              rows=int((lens > 0).sum()))
-
-    def _extend_inner(self, session: DecodeSession,
-                      new_tokens: List[List[int]], lens) -> None:
-        B = session.batch
         if session.allocator is not None:
             # prefill needs full coverage: map blocks for every new token
             # before any position is written (no partial prefills)
-            for i, n in enumerate(lens):
-                if n == 0:
-                    continue
-                start = int(session.lengths[i])
-                target = start + int(n)
-                if session.allocator.ensure(i, target) < target \
-                        or not self._cow_range(session, i, start, target):
-                    raise RuntimeError(
-                        f"paged KV pool exhausted: row {i} needs "
-                        f"{session.allocator.blocks_for(target)} blocks, "
-                        f"{session.allocator.free_count} free; raise "
-                        f"num_blocks or gate admission on free blocks")
+            copies: List[Tuple[int, int, int]] = []
+            with obs.span("engine/alloc"):
+                for i, n in enumerate(lens):
+                    if n == 0:
+                        continue
+                    start = int(session.lengths[i])
+                    target = start + int(n)
+                    if session.allocator.ensure(i, target) < target \
+                            or not self._cow_plan(session, i, start, target,
+                                                  copies):
+                        raise RuntimeError(
+                            f"paged KV pool exhausted: row {i} needs "
+                            f"{session.allocator.blocks_for(target)} blocks, "
+                            f"{session.allocator.free_count} free; raise "
+                            f"num_blocks or gate admission on free blocks")
+            self._cow_copy(session, copies)
             self._sync_tables(session)
+        with obs.span("engine/prefill", tokens=int(lens.sum()),
+                      rows=int((lens > 0).sum())):
+            self._prefill(session, new_tokens, lens)
+
+    def _prefill(self, session: DecodeSession, new_tokens: List[List[int]],
+                 lens) -> None:
+        """Pad, dispatch the prefill program, gather ``last_logits``."""
+        B = session.batch
         L = _bucket(int(lens.max()))
         toks = np.full((B, L), self.pad_id, np.int32)
         pos = np.zeros((B, L), np.int32)
@@ -1052,16 +1090,17 @@ class GenerationEngine:
         idx = np.asarray(list(rows), np.int64)
         if idx.size == 0:
             return
-        if session.allocator is not None:
-            freed = session.allocator.free_rows(idx)
-            session.cache = self.model.reset_cache_rows(
-                session.cache, idx, self.max_len, self.window,
-                policy=session.cache_policy, freed_blocks=freed)
-            self._sync_tables(session)
-        else:
-            session.cache = self.model.reset_cache_rows(
-                session.cache, idx, self.max_len, self.window)
-        session.last_logits = session.last_logits.at[jnp.asarray(idx)].set(0.0)
+        with obs.span("engine/reset", rows=int(idx.size)):
+            a = session.allocator
+            with obs.span("engine/free"):
+                freed = None if a is None else a.free_rows(idx)
+                session.cache = self.model.reset_cache_rows(
+                    session.cache, idx, self.max_len, self.window,
+                    policy=session.cache_policy, freed_blocks=freed)
+            if a is not None:
+                self._sync_tables(session)
+            session.last_logits = session.last_logits.at[
+                jnp.asarray(idx)].set(0.0)
         lengths = np.asarray(session.lengths).copy()
         lengths[idx] = 0
         session.lengths = lengths
@@ -1110,9 +1149,47 @@ class GenerationEngine:
             budgets = np.minimum(budgets, np.asarray(row_budgets, np.int32))
         offsets = (np.zeros((B,), np.int32) if step_offsets is None
                    else np.asarray(step_offsets, np.int32))
-        if session.allocator is not None:
+        with obs.span("engine/generate", width=T):
+            if session.allocator is not None:
+                self._map_decode_blocks(session, budgets)
+            stop_arr = jnp.asarray(np.asarray(self.stop_ids, np.int32)
+                                   .reshape(-1))
+            with obs.span("engine/decode_loop"):
+                toks, lps, counts, cache, last_logits, lengths, stopped = \
+                    self._loop_jit(
+                        self.params, session.cache, session.last_logits,
+                        jnp.asarray(session.lengths, jnp.int32),
+                        jnp.asarray(session.stopped),
+                        None if per_row else key,
+                        jnp.asarray(row_keys) if per_row else None,
+                        jnp.int32(int(budgets.max(initial=0))),
+                        jnp.float32(temp), stop_arr, session.cross_kv,
+                        jnp.asarray(offsets), jnp.asarray(budgets), T=T,
+                        per_row=per_row)
+            session.cache = cache
+            session.last_logits = last_logits
+            # single host materialization per turn
+            with obs.span("engine/fetch"):
+                toks, lps, counts, lengths, stopped = jax.device_get(
+                    (toks, lps, counts, lengths, stopped))
+        # writable host copies (device_get buffers are read-only; rollout
+        # mutates session.stopped per row)
+        session.lengths = np.array(lengths, np.int64)
+        session.stopped = np.array(stopped, bool)
+        return GenerationResult(tokens=np.asarray(toks),
+                                logprobs=np.asarray(lps),
+                                counts=np.asarray(counts))
+
+    def _map_decode_blocks(self, session: DecodeSession,
+                           budgets: np.ndarray) -> None:
+        """Map blocks for each live row's decode budget (in place: a row
+        the pool cannot cover shrinks to its mapped capacity, 0 = starved
+        this call), copy-on-write the shared blocks its writes will reach,
+        and push the table to the device."""
+        copies: List[Tuple[int, int, int]] = []
+        with obs.span("engine/alloc"):
             stopped_now = np.asarray(session.stopped)
-            for r in range(B):
+            for r in range(session.batch):
                 if stopped_now[r] or budgets[r] <= 0:
                     continue
                 cur = int(session.lengths[r])
@@ -1123,34 +1200,11 @@ class GenerationEngine:
                 # a block shared with the row's prompt-group (the partial
                 # tail); give the row a private copy before the device loop
                 # writes.  A failed copy starves the row this call.
-                if budgets[r] > 0 and not self._cow_range(
-                        session, r, cur, cur + int(budgets[r])):
+                if budgets[r] > 0 and not self._cow_plan(
+                        session, r, cur, cur + int(budgets[r]), copies):
                     budgets[r] = 0
-            self._sync_tables(session)
-        stop_arr = jnp.asarray(np.asarray(self.stop_ids, np.int32)
-                               .reshape(-1))
-        toks, lps, counts, cache, last_logits, lengths, stopped = \
-            self._loop_jit(
-                self.params, session.cache, session.last_logits,
-                jnp.asarray(session.lengths, jnp.int32),
-                jnp.asarray(session.stopped),
-                None if per_row else key,
-                jnp.asarray(row_keys) if per_row else None,
-                jnp.int32(int(budgets.max(initial=0))), jnp.float32(temp),
-                stop_arr, session.cross_kv, jnp.asarray(offsets),
-                jnp.asarray(budgets), T=T, per_row=per_row)
-        session.cache = cache
-        session.last_logits = last_logits
-        # single host materialization per turn
-        toks, lps, counts, lengths, stopped = jax.device_get(
-            (toks, lps, counts, lengths, stopped))
-        # writable host copies (device_get buffers are read-only; rollout
-        # mutates session.stopped per row)
-        session.lengths = np.array(lengths, np.int64)
-        session.stopped = np.array(stopped, bool)
-        return GenerationResult(tokens=np.asarray(toks),
-                                logprobs=np.asarray(lps),
-                                counts=np.asarray(counts))
+        self._cow_copy(session, copies)
+        self._sync_tables(session)
 
     def generate_reference(self, session: DecodeSession, max_new_tokens: int,
                            key: Optional[jax.Array] = None,
@@ -1176,22 +1230,10 @@ class GenerationEngine:
         offsets = (np.zeros((B,), np.int32) if step_offsets is None
                    else np.asarray(step_offsets, np.int32))
         if session.allocator is not None:
-            # same block mapping as the fused path: without it, decoded
-            # positions past the last mapped block would route to the trash
-            # block and silently vanish from attention
-            stopped_now = np.asarray(session.stopped)
-            for r in range(B):
-                if stopped_now[r] or budgets[r] <= 0:
-                    continue
-                cur = int(session.lengths[r])
-                target = min(cur + int(budgets[r]), self.max_len)
-                cap = session.allocator.ensure(r, target)
-                budgets[r] = max(0, min(int(budgets[r]), cap - cur))
-                # same CoW barrier as the fused path (parity oracle)
-                if budgets[r] > 0 and not self._cow_range(
-                        session, r, cur, cur + int(budgets[r])):
-                    budgets[r] = 0
-            self._sync_tables(session)
+            # same block mapping and CoW barrier as the fused path: without
+            # it, decoded positions past the last mapped block would route
+            # to the trash block and silently vanish from attention
+            self._map_decode_blocks(session, budgets)
         active = (~session.stopped & (session.lengths < self.max_len - 1)
                   & (budgets > 0))
 

@@ -175,6 +175,37 @@ def test_radix_lru_eviction_under_pressure(setup):
     _assert_parity(r_on, r_off)
 
 
+def test_cow_into_an_evicted_radix_block_keeps_the_copy(setup):
+    """With the free list empty, a copy-on-write takes a block the radix
+    evicts in the same call.  The evicted block's reset must come before
+    the copy, not after it: decode stays token-identical to unshared
+    paging."""
+    cfg, model, params, tok = setup
+    cached, group = _ids(40, seed=1), _ids(40, seed=2)   # 2 full + 8
+    rk = jax.random.split(jax.random.PRNGKey(1), 3)
+
+    on = _engine(model, params, tok, sharing=True, num_blocks=16)
+    s = on.start([cached, group, group])
+    on.reset_rows(s, [0])           # its 2 full blocks stay cached
+    a = s.allocator
+    assert a.cached_count == 2
+    on.extend_rows(s, [0], [_ids(BS * a.free_count - BS * 2, seed=3)])
+    assert a.free_count == a.cached_count == 2   # only the cached chain
+    res_on = on.generate(s, 8, None, row_keys=rk)
+    assert a.cow_count == 1 and a.prefix.evictions == 2
+    a.check()
+
+    off = _engine(model, params, tok, sharing=False)
+    s2 = off.start([cached, group, group])
+    off.reset_rows(s2, [0])
+    res_off = off.generate(s2, 8, None, row_keys=rk)
+    # rows 1-2: the group; row 1 copied its tail into an evicted block
+    for la, lb in zip(res_on.logprob_lists()[1:],
+                      res_off.logprob_lists()[1:]):
+        np.testing.assert_allclose(la, lb, atol=1e-5)
+    assert res_on.token_lists()[1:] == res_off.token_lists()[1:]
+
+
 def test_scheduler_parity_and_prefix_stats(setup):
     """Under the continuous scheduler, sharing-on paged rollouts reproduce
     the contiguous reference token-for-token, the new rollout stats report a

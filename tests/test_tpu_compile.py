@@ -11,6 +11,7 @@ imported: only one process may load the TPU library, and it keeps it until
 it exits.  Keep every such compile in this one file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +72,9 @@ def test_paged_kernel_compiles_for_v5e(one_chip, kv_dtype):
 
     compiled = jax.jit(step).lower(q, pool, q_pos).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the kernel's stable name, which profiles read it by
+    assert re.search(r"%paged_attention[.\d]* = .*custom-call",
+                     compiled.as_text())
 
 
 def test_engine_paged_decode_step_compiles_for_v5e(one_chip, monkeypatch):
