@@ -87,6 +87,16 @@ def _paged_setup(seed, B, H, Hk, D, Dv, N, bs, T, lengths):
     return q, k_pool, v_pool, jnp.asarray(table), q_pos
 
 
+def _assert_live_match_dead_zero(out, ref, q_pos):
+    """Live rows match the reference; dead rows (q_pos < 0) are exact
+    zeros, where the reference's fully masked softmax is not."""
+    live = np.asarray(q_pos) >= 0
+    out = np.asarray(out)
+    np.testing.assert_allclose(out[live], np.asarray(ref)[live],
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(out[~live], 0.0)
+
+
 @pytest.mark.parametrize("B,H,Hk,D,bs,lengths", [
     (3, 4, 2, 32, 16, [41, 8, 64]),
     (2, 4, 1, 64, 8, [5, 23]),       # MQA, partial blocks
@@ -95,6 +105,16 @@ def _paged_setup(seed, B, H, Hk, D, Dv, N, bs, T, lengths):
     (2, 6, 2, 32, 16, [31, 17]),     # G=3 (not a multiple of 8)
     (2, 4, 2, 32, 16, [1, 9]),       # q_pos=0 (single-token row)
     (3, 4, 2, 32, 16, [16, 17, 32]),  # q_pos on/just past block boundaries
+    # P pages per grid step (pages_per_step): the table width T is the
+    # longest row's block count; a 0 length is a dead row (q_pos = -1)
+    (3, 4, 2, 32, 64, [500, 192, 576]),      # T=9, P=3: rows of 3 chunks,
+                                             # one ending on a chunk boundary
+    (4, 4, 2, 32, 64, [193, 384, 385, 576]),  # P=3: one past a boundary
+    (3, 4, 2, 32, 128, [640, 1, 129]),       # T=5, P=1: a block per step
+    (3, 4, 2, 32, 16, [640, 321, 320]),      # T=40, P=20: two chunks
+    (3, 28, 4, 32, 64, [576, 200, 0]),       # G=7 (Qwen2.5-7B's group), P=3
+    (4, 4, 2, 32, 16, [0, 1, 640, 300]),     # dead, length-1 and full rows
+    (5, 4, 2, 32, 16, [300, 0, 0, 640, 1]),  # dead rows between live ones
 ])
 def test_paged_attention_kernel_matches_ref(B, H, Hk, D, bs, lengths):
     from repro.kernels.paged_attention import paged_attention_fwd
@@ -105,14 +125,24 @@ def test_paged_attention_kernel_matches_ref(B, H, Hk, D, bs, lengths):
     out = paged_attention_fwd(q, k_pool, v_pool, table, q_pos,
                               interpret=True)
     ref = R.paged_attention_ref(q, k_pool, v_pool, table, q_pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+    _assert_live_match_dead_zero(out, ref, q_pos)
+
+
+@pytest.mark.parametrize("bs,T,P", [
+    (16, 128, 32),      # the search-rollout cell: 512 positions a step
+    (16, 40, 20), (64, 9, 3),
+    (128, 5, 1),        # no divisor P > 1 fits: one block a step
+    (16, 127, 1),
+])
+def test_pages_per_step(bs, T, P):
+    from repro.kernels.paged_attention import pages_per_step
+    assert pages_per_step(bs, T) == P
 
 
 def test_paged_attention_dead_rows_are_exact_zero():
     """Rows with q_pos=-1 (invalid / dead lanes) must produce exactly 0 —
     including the edge where the row's table is ALL trash (-1): the kernel
-    then visits only the trash block and its softmax accumulator stays
+    then copies and computes nothing and its softmax accumulator stays
     empty, which ``_flush`` must not turn into garbage/NaN."""
     from repro.kernels.paged_attention import paged_attention_fwd
     B, H, Hk, D, bs, T = 3, 4, 2, 32, 16, 3
@@ -135,15 +165,29 @@ def test_paged_attention_dead_rows_are_exact_zero():
                                atol=2e-5, rtol=2e-5)
 
 
-def test_paged_attention_int8_matches_dequantized_ref():
+@pytest.mark.parametrize("B,H,Hk,D,bs,lengths,shared", [
+    (2, 4, 2, 32, 16, [33, 17], 0),
+    (4, 4, 2, 32, 64, [193, 384, 385, 576], 0),   # P=3, chunk boundaries
+    (3, 4, 2, 32, 128, [640, 1, 129], 0),         # P=1
+    (3, 28, 4, 32, 64, [576, 200, 0], 0),         # G=7, a dead row
+    (4, 4, 2, 32, 16, [0, 1, 640, 300], 0),       # dead, length-1, full
+    (3, 4, 2, 32, 16, [640, 400, 50], 20),        # rows 0 and 1 share 20
+])                                                # blocks (a prefix)
+def test_paged_attention_int8_matches_dequantized_ref(B, H, Hk, D, bs,
+                                                      lengths, shared):
     """int8 pools + per-(block,slot,head) scales: the kernel's in-loop
     dequantization must match the reference run on explicitly dequantized
-    fp pools to fp accuracy (the quantization error itself cancels)."""
+    fp pools to fp accuracy (the quantization error itself cancels).
+    ``shared``: row 1's first table slots point at row 0's blocks, as
+    after copy-on-write prefix sharing."""
     from repro.kernels.paged_attention import paged_attention_fwd
     from repro.models.attention import _quantize_int8
-    B, H, Hk, D, bs, T = 2, 4, 2, 32, 16, 3
+    T = max((n + bs - 1) // bs for n in lengths)
+    N = sum((n + bs - 1) // bs for n in lengths) + 2
     q, k_pool, v_pool, table, q_pos = _paged_setup(
-        3, B, H, Hk, D, D, 9, bs, T, [33, 17])
+        3, B, H, Hk, D, D, N, bs, T, lengths)
+    if shared:
+        table = table.at[1, :shared].set(table[0, :shared])
     kq, ks = _quantize_int8(k_pool)
     vq, vs = _quantize_int8(v_pool)
     out = paged_attention_fwd(q, kq, vq, table, q_pos,
@@ -151,8 +195,7 @@ def test_paged_attention_int8_matches_dequantized_ref():
     k_deq = kq.astype(jnp.float32) * ks[..., None]
     v_deq = vq.astype(jnp.float32) * vs[..., None]
     ref = R.paged_attention_ref(q, k_deq, v_deq, table, q_pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+    _assert_live_match_dead_zero(out, ref, q_pos)
 
 
 def test_paged_attention_int8_vs_fp_oracle_tolerance():

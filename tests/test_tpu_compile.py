@@ -49,20 +49,18 @@ def _on(sharding, tree):
         a.shape, a.dtype, sharding=sharding), tree)
 
 
-@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
-def test_paged_kernel_compiles_for_v5e(one_chip, kv_dtype):
-    """The decode kernel over the engine's pool layout (head-major, trash
-    block last), with bf16 or int8 pools and their per-slot scales."""
+def _compile_paged_kernel(sharding, cfg, batch, kv_dtype):
+    """Compile the decode kernel over the engine's pool layout (head-major,
+    trash block last) for one layer of ``cfg``."""
     from repro.kernels.paged_attention import paged_attention_fwd
-    cfg = get_config(ARCH)
     pool = jax.eval_shape(lambda: PagedCache(PAGE, N_BLOCKS, kv_dtype)
                           .init_cache(Model(dataclasses.replace(
-                              cfg, n_layers=1)), B, MAX_LEN))["stack"]
-    pool = _on(one_chip, jax.tree.map(
+                              cfg, n_layers=1)), batch, MAX_LEN))["stack"]
+    pool = _on(sharding, jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), pool))
-    q = jax.ShapeDtypeStruct((B, cfg.n_heads, cfg.head_dim), jnp.bfloat16,
-                             sharding=one_chip)
-    q_pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    q = jax.ShapeDtypeStruct((batch, cfg.n_heads, cfg.head_dim),
+                             jnp.bfloat16, sharding=sharding)
+    q_pos = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=sharding)
 
     def step(q, pool, q_pos):
         return paged_attention_fwd(q, pool["k"], pool["v"], pool["table"],
@@ -70,11 +68,35 @@ def test_paged_kernel_compiles_for_v5e(one_chip, kv_dtype):
                                    v_scale=pool.get("v_scale"),
                                    interpret=False)
 
-    compiled = jax.jit(step).lower(q, pool, q_pos).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    # the kernel leaves VMEM at the chip's default scoped limit, so the
+    # compile below is refused if its buffers overflow it
+    (call,) = [e for e in jax.make_jaxpr(step)(q, pool, q_pos).jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes \
+        is None
+    text = jax.jit(step).lower(q, pool, q_pos).compile().as_text()
+    assert "tpu_custom_call" in text
     # the kernel's stable name, which profiles read it by
-    assert re.search(r"%paged_attention[.\d]* = .*custom-call",
-                     compiled.as_text())
+    assert re.search(r"%paged_attention[.\d]* = .*custom-call", text)
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_paged_kernel_compiles_for_v5e(one_chip, kv_dtype):
+    """The decode kernel over the engine's pool layout (head-major, trash
+    block last), with bf16 or int8 pools and their per-slot scales."""
+    _compile_paged_kernel(one_chip, get_config(ARCH), B, kv_dtype)
+
+
+@pytest.mark.parametrize("arch,kv_dtype", [
+    (ARCH, "fp"), (ARCH, "int8"),       # 8 kv heads of 128, groups of 4
+    ("qwen2-7b", "fp"),                 # 4 kv heads of 128, groups of 7
+])
+def test_paged_kernel_compiles_at_cell_shapes(one_chip, arch, kv_dtype):
+    """The search-rollout cell's decode shapes: 16 rows, a table of 128
+    slots of 16-token blocks, so 32 pages (512 positions) a grid step."""
+    from repro.kernels.paged_attention import pages_per_step
+    assert pages_per_step(PAGE, MAX_LEN // PAGE) == 32
+    _compile_paged_kernel(one_chip, get_config(arch), 16, kv_dtype)
 
 
 def test_engine_paged_decode_step_compiles_for_v5e(one_chip, monkeypatch):
