@@ -1,6 +1,7 @@
-"""What every cell shares: finding the cell and its files by name, the
-compile cache, the chip check, compile counting, the traced window, the
-metric readers and the result line.
+"""What every cell shares: finding the cell and its files by name (the
+configuration's layout and reference modules among them), the compile
+cache, the chip check, compile counting, the traced window, the metric
+readers and the result line.
 
 The command's checkout root holds ``BENCHMARK.json``; everything the
 benchmark reads lies under this directory, found by the names there.
@@ -8,6 +9,7 @@ benchmark reads lies under this directory, found by the names there.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -19,6 +21,7 @@ from typing import Optional
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+CONFIGS = HERE / "configs"
 CACHE_DIR = ROOT / ".jax_cache"
 OUT_DIR = HERE / "out"
 
@@ -46,6 +49,11 @@ def load_cell(name: str) -> Cell:
     w = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
     cfg = json.loads((ROOT / conf["file"]).read_text())
+    for key in ("layout", "reference"):
+        if not (CONFIGS / f"{cfg.get(key)}.py").is_file():
+            raise SystemExit(f"configuration {conf['name']!r} names no "
+                             f"{key} module {cfg.get(key)!r} in {CONFIGS}")
+        config_module(cfg, key)
     traffic = json.loads((HERE / "traffic" / f"{w['traffic']}.json")
                          .read_text())
     e2e = [m for m in bench["end_to_end"] if name in m.get("workloads",
@@ -57,6 +65,25 @@ def load_cell(name: str) -> Cell:
     lim_path = HERE / "limits" / f"{name}.json"
     limits = json.loads(lim_path.read_text()) if lim_path.exists() else {}
     return Cell(name, int(w["chips"]), cfg, traffic, e2e, per_layer, limits)
+
+
+def config_module(cfg: dict, key: str):
+    """The module ``configs/<cfg[key]>.py`` that a configuration names:
+    ``layout``, all that the harness knows of the model family, or
+    ``reference``, the plain reference."""
+    return _load(CONFIGS / f"{cfg[key]}.py")
+
+
+def layout(cfg: dict):
+    return config_module(cfg, "layout")
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: Path):
+    spec = importlib.util.spec_from_file_location(path.stem, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def enable_cache() -> str:

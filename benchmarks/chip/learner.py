@@ -24,7 +24,6 @@ import program as P
 import traffic as TR
 import weights as W
 from harness import Span, log
-from rollout import reference_module
 
 from repro.core.grpo import GRPOConfig
 from repro.core.mdp import Role, Trajectory
@@ -51,18 +50,19 @@ def trajectories(group: list, group_id: int) -> list:
     return out
 
 
-def leaf_norms(tree) -> dict:
+def leaf_norms(cfg, tree) -> dict:
     """(name, layer) -> float norm of each per-layer slice of a program
-    tree, computed on the device."""
-    leaves = P.layer_leaves(tree)
+    tree (the layout's ``layer_leaves``), computed on the device."""
+    leaves = H.layout(cfg).layer_leaves(tree)
     names = list(leaves)
     vals = jax.device_get([jnp.sqrt(jnp.sum(jnp.square(
         leaves[n].astype(jnp.float32)))) for n in names])
     return {n: float(v) for n, v in zip(names, vals)}
 
 
-def diff_norms(a, b) -> dict:
-    la, lb = P.layer_leaves(a), P.layer_leaves(b)
+def diff_norms(cfg, a, b) -> dict:
+    leaves = H.layout(cfg).layer_leaves
+    la, lb = leaves(a), leaves(b)
     names = list(la)
     vals = jax.device_get([jnp.sqrt(jnp.sum(jnp.square(
         la[n].astype(jnp.float32) - lb[n].astype(jnp.float32))))
@@ -88,9 +88,8 @@ def program_part(cell, seed, seconds, traced, t_start, batches, fault):
     values only, so that the learner's device state dies with the call."""
     cfg, mix = cell.config, cell.traffic
     opt, grpo = mix["optimizer"], mix["grpo"]
-    pcfg = P.model_config(cfg)
-    model = P.Model(pcfg)
-    tok = default_tokenizer(pcfg.vocab_size)
+    model = P.model_of(cfg)
+    tok = default_tokenizer(model.cfg.vocab_size)
     groups = [trajectories(g, i) for i, g in enumerate(batches)]
     advs = [np.array([t["adv"] for t in g], np.float32) for g in batches]
     learner = Learner(
@@ -127,9 +126,9 @@ def program_part(cell, seed, seconds, traced, t_start, batches, fault):
         metrics = step(i)
         losses.append(float(metrics["loss"]))
         if i == 0:
-            grads = {k: v / (1 - opt["b1"])
-                     for k, v in leaf_norms(learner.opt_state["m"]).items()}
-    change = diff_norms(learner.params, p0)
+            m1 = leaf_norms(cfg, learner.opt_state["m"])
+            grads = {k: v / (1 - opt["b1"]) for k, v in m1.items()}
+    change = diff_norms(cfg, learner.params, p0)
     del p0
     for i in range(n_chk, len(groups)):      # every remaining batch shape
         step(i)
@@ -181,8 +180,7 @@ def reference_steps(ref, cfg, mix, seed, batches, dtype, rows=None):
     n = cfg["num_hidden_layers"]
     init = jax.jit(lambda k: {
         "layers": [W.layer_weights(k, cfg, i, dtype) for i in range(n)],
-        **{g: W.global_tensor(k, cfg, g, dtype) for g in W.global_shapes(cfg)}
-    })
+        **W.global_weights(k, cfg, dtype)})
     params = init(key)
     state = {m: jax.tree_util.tree_map(
         lambda p: jnp.zeros(p.shape, jnp.float32), params) for m in "mv"}
@@ -288,7 +286,7 @@ def check(cfg, mix, seed, batches, losses, grads, change, variants):
     the limits' upper ends: the reference one precision lower (bfloat16
     parameters and activations) and the half-batch fault, each put in the
     program's place."""
-    ref = reference_module(cfg)
+    ref = H.config_module(cfg, "reference")
     full = reference_steps(ref, cfg, mix, seed, batches, jnp.float32)
     scales = loss_scales(mix, batches)
     numbers = numbers_of(losses, grads, change, full, scales)

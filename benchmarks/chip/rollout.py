@@ -381,8 +381,8 @@ def run(cell: H.Cell, seed: int, seconds: float, traced: bool,
     only).  ``reuse`` (calibration only) keeps one engine
     across seeds, its weights swapped in place."""
     cfg, mix = cell.config, cell.traffic
-    pcfg = P.model_config(cfg)
-    model = P.Model(pcfg)
+    model = P.model_of(cfg)
+    pcfg = model.cfg
     tok0 = default_tokenizer(pcfg.vocab_size)
     plan = TR.rollout_plan(mix, seed)
     drv = Driver(mix, seconds)
@@ -432,7 +432,7 @@ def run(cell: H.Cell, seed: int, seconds: float, traced: bool,
         f"{drv.c['cache_loads']} loaded from the compile cache, "
         f"{drv.c['traces']} traces: {drv.compiled_names}")
     peak_bytes = H.memory_peak_bytes(cell.chips)
-    live = live_values(drv, mix["page"])
+    live = live_values(drv, cfg, mix["page"])
     drv.session = None
     del worker, proxy, engine, executor
     gc.collect()
@@ -468,24 +468,15 @@ def sample_jobs(done: dict, n: int, seed: int) -> list:
     return [longest] + [int(j) for j in pick]
 
 
-def live_values(drv: Driver, page: int) -> list:
-    """(row, tokens in cache, value vectors (n, kv_heads, D) in float32)
-    of each row live when the window closed, as layer 0 of the paged pool
-    holds them (an int8 pool's values times their scales)."""
+def live_values(drv: Driver, cfg: dict, page: int) -> list:
+    """(row, tokens in cache, float32 vectors (n, ...)) of each row live
+    when the window closed: the layer-0 pool vectors that the layout's
+    ``cache_vectors`` reads."""
     s = drv.session
-    c = s.cache["stack"]
-    pool = np.asarray(jax.device_get(c["v"][0].astype(jnp.float32)))
-    if "v_scale" in c:
-        pool = pool * np.asarray(jax.device_get(c["v_scale"][0]))[..., None]
-    table = np.asarray(jax.device_get(c["table"][0]))
-    out = []
-    for row in sorted(drv.row_job):
-        n = int(s.lengths[row])
-        if n == 0:
-            continue
-        v = pool[table[row, :-(-n // page)]].transpose(0, 2, 1, 3)
-        out.append((row, n, v.reshape(-1, *v.shape[2:])[:n]))
-    return out
+    rows = [r for r in sorted(drv.row_job) if int(s.lengths[r]) > 0]
+    lengths = [int(s.lengths[r]) for r in rows]
+    vecs = H.layout(cfg).cache_vectors(s.cache, rows, lengths, page)
+    return list(zip(rows, lengths, vecs))
 
 
 def expected_obs(seed, plan, tok, manager, j: int, n: int) -> list:
@@ -519,7 +510,7 @@ def check(cfg, mix, seed, plan, drv, done, live, tok, manager,
         return {"served_gap": float("inf"), "logprob_gap": float("inf"),
                 "value_cache_gap": float("inf"),
                 "context_mismatch": 1.0}, {}
-    ref = reference_module(cfg)
+    ref = H.config_module(cfg, "reference")
     jobs = sample_jobs(done, mix["check_trajectories"], seed)
     n_jobs = drv.n_jobs
     job_keys = jax.random.split(stream_key(seed), n_jobs)
@@ -605,19 +596,11 @@ def check(cfg, mix, seed, plan, drv, done, live, tok, manager,
             "context_mismatch": float(mismatch)}, extra
 
 
-def reference_module(cfg: dict):
-    import importlib.util
-    path = H.HERE / "configs" / f"{cfg['reference']}.py"
-    spec = importlib.util.spec_from_file_location(cfg["reference"], path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def reference_values(ref, cfg, seed, tokens: np.ndarray,
                      dtype=jnp.float32) -> np.ndarray:
-    """Layer 0's value vectors (R, L, kv_heads, D) of the rows' tokens,
-    in the reference's ``dtype`` (``ref.FP8`` for the control)."""
+    """Layer 0's cached vectors (R, L, ...) of the rows' tokens, as the
+    reference's ``layer_values`` gives them, in its ``dtype``
+    (``ref.FP8`` for the control)."""
     cast = served_weights(cfg)
 
     @jax.jit
@@ -651,21 +634,23 @@ def served_weights(cfg):
 
 def reference_hidden(ref, cfg, seed, tokens: np.ndarray,
                      dtype=jnp.float32) -> np.ndarray:
-    """Last hidden states (R, L, d) of the rows, one layer at a time, in
-    the reference's ``dtype`` (``ref.FP8`` for the control)."""
+    """Last hidden states (R, L, d) of the rows, one layer at a time (one
+    program for each of the layout's groups of like layers), in the
+    reference's ``dtype`` (``ref.FP8`` for the control)."""
     key = W.base_key(seed)
     cast = served_weights(cfg)
     emb = jax.jit(lambda k: cast(W.global_tensor(k, cfg, "embed")))(key)
     x = emb[jnp.asarray(tokens)]
     del emb
+    for first, n in H.layout(cfg).layer_groups(cfg):
+        @jax.jit
+        def one_layer(x, k, layer, first=first):
+            w = jax.tree_util.tree_map(cast, W.layer_weights(
+                k, cfg, layer, like=first))
+            return jax.lax.map(lambda seq: ref.layer(seq, w, cfg, dtype), x)
 
-    @jax.jit
-    def one_layer(x, k, layer):
-        w = jax.tree_util.tree_map(cast, W.layer_weights(k, cfg, layer))
-        return jax.lax.map(lambda seq: ref.layer(seq, w, cfg, dtype), x)
-
-    for layer in range(cfg["num_hidden_layers"]):
-        x = one_layer(x, key, jnp.int32(layer))
+        for layer in range(first, first + n):
+            x = one_layer(x, key, jnp.int32(layer))
     return np.asarray(x)
 
 
@@ -677,8 +662,8 @@ def served_gaps(ref, cfg, seed, h, served, lp_prog, keys, temperature,
     logprob of that token, are judged against the reference."""
     key = W.base_key(seed)
     cast = served_weights(cfg)
-    fn = jax.jit(lambda k: {n: cast(W.global_tensor(k, cfg, n))
-                            for n in W.global_shapes(cfg)})
+    fn = jax.jit(lambda k: jax.tree_util.tree_map(
+        cast, W.global_weights(k, cfg)))
     g = fn(key)
     out_w = g["embed"] if cfg.get("tie_word_embeddings") else g["unembed"]
     V = cfg["vocab_size"]

@@ -11,17 +11,14 @@ sys.path.insert(0, str(HERE))
 
 import harness as H  # noqa: E402
 
-TINY_MODEL = {"num_hidden_layers": 2, "hidden_size": 64,
-              "intermediate_size": 128, "num_attention_heads": 4,
-              "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 512}
-
 
 def tiny_cell(workload: str, config_over=None, **traffic_over) -> H.Cell:
     """The cell ``<config>.<mix>`` built from its files (whether or not
-    ``BENCHMARK.json`` lists it yet), cut to a tiny size."""
+    ``BENCHMARK.json`` lists it yet), cut to the tiny widths its layout
+    names (``TINY``)."""
     conf, mix_name = workload.split(".", 1)
     cfg = json.loads((HERE / "configs" / f"{conf}.json").read_text())
-    cfg = dict(cfg, **TINY_MODEL, **(config_over or {}))
+    cfg = dict(cfg, **H.layout(cfg).TINY, **(config_over or {}))
     mix = json.loads((HERE / "traffic" / f"{mix_name}.json").read_text())
     if mix["entry"] == "rollout":
         mix.update(slots=4, max_len=512, pool_blocks=96, group_size=2,

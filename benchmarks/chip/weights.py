@@ -6,59 +6,25 @@ so the program's stacked tree (all layers in one jitted call) and the plain
 reference (one layer at a time, after the window) draw identical values
 without either handing the other anything.
 
-Tensors are in the plain orientation of the reference (``x @ w``), named
-after the Hugging Face modules they stand for.  Matrices are normal with
-standard deviation 1/sqrt(fan_in); norm scales are 1 + 0.1 * normal, so a
-norm that ignores its scale shows; the embedding is normal(0, 0.02).
+Which tensors there are, their shapes, ids and runs of like layers come
+from the configuration's layout module (``harness.layout``).  Tensors are
+in the plain orientation of the reference (``x @ w``), named after the
+Hugging Face modules they stand for.  Matrices are normal with standard
+deviation 1/sqrt(fan_in); norm scales are 1 + 0.1 * normal, so a norm
+that ignores its scale shows; the embedding is normal(0, 0.02).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from flops import dims
-
-# stable ids: changing one changes every weight drawn from it
-GLOBAL_IDS = {"embed": 1, "final_norm": 2, "unembed": 3}
-LAYER_IDS = {"ln1": 10, "q": 11, "k": 12, "v": 13, "o": 14, "q_norm": 15,
-             "k_norm": 16, "ln2": 17, "gate": 18, "up": 19, "down": 20,
-             "q_bias": 21, "k_bias": 22, "v_bias": 23}
+import harness as H
 
 
 def base_key(seed: int) -> jax.Array:
     """A key from any whole number: its low and high 32 bits."""
     s = int(seed) % (1 << 64)
     return jax.random.fold_in(jax.random.PRNGKey(s & 0xFFFFFFFF), s >> 32)
-
-
-def layer_shapes(cfg: dict) -> dict:
-    """name -> (shape, kind), kind "matrix" (with its fan-in), "norm" or
-    "bias"."""
-    m = dims(cfg)
-    d, H, Hk, D, ff = m["d"], m["H"], m["Hk"], m["D"], m["ff"]
-    out = {"ln1": ((d,), "norm"), "ln2": ((d,), "norm"),
-           "q": ((d, H, D), ("matrix", d)), "k": ((d, Hk, D), ("matrix", d)),
-           "v": ((d, Hk, D), ("matrix", d)),
-           "o": ((H, D, d), ("matrix", H * D)),
-           "gate": ((d, ff), ("matrix", d)), "up": ((d, ff), ("matrix", d)),
-           "down": ((ff, d), ("matrix", ff))}
-    if cfg.get("use_qk_norm", False):
-        out["q_norm"] = ((D,), "norm")
-        out["k_norm"] = ((D,), "norm")
-    if cfg.get("attention_bias", False):
-        out["q_bias"] = ((H, D), "bias")
-        out["k_bias"] = ((Hk, D), "bias")
-        out["v_bias"] = ((Hk, D), "bias")
-    return out
-
-
-def global_shapes(cfg: dict) -> dict:
-    m = dims(cfg)
-    out = {"embed": ((m["V"], m["d"]), "embed"),
-           "final_norm": ((m["d"],), "norm")}
-    if not cfg.get("tie_word_embeddings", False):
-        out["unembed"] = ((m["d"], m["V"]), ("matrix", m["d"]))
-    return out
 
 
 def _draw(key, shape, kind):
@@ -72,31 +38,43 @@ def _draw(key, shape, kind):
     return z * (1.0 / float(kind[1]) ** 0.5)
 
 
-def layer_tensor(key, cfg: dict, name: str, layer, dtype=jnp.float32):
-    shape, kind = layer_shapes(cfg)[name]
-    k = jax.random.fold_in(jax.random.fold_in(key, LAYER_IDS[name]), layer)
+def layer_tensor(key, cfg: dict, name: str, layer, dtype=jnp.float32,
+                 like=None):
+    """Tensor ``name`` of layer ``layer``.  ``layer`` may be traced where
+    ``like``, a layer of the same group given as a number, names the
+    shapes."""
+    LY = H.layout(cfg)
+    shape, kind = LY.layer_shapes(cfg, layer if like is None else like)[name]
+    k = jax.random.fold_in(jax.random.fold_in(key, LY.LAYER_IDS[name]), layer)
     return _draw(k, shape, kind).astype(dtype)
 
 
 def global_tensor(key, cfg: dict, name: str, dtype=jnp.float32):
-    shape, kind = global_shapes(cfg)[name]
-    return _draw(jax.random.fold_in(key, GLOBAL_IDS[name]), shape,
+    LY = H.layout(cfg)
+    shape, kind = LY.global_shapes(cfg)[name]
+    return _draw(jax.random.fold_in(key, LY.GLOBAL_IDS[name]), shape,
                  kind).astype(dtype)
 
 
-def layer_weights(key, cfg: dict, layer, dtype=jnp.float32) -> dict:
-    return {n: layer_tensor(key, cfg, n, layer, dtype)
-            for n in layer_shapes(cfg)}
+def global_weights(key, cfg: dict, dtype=jnp.float32) -> dict:
+    return {n: global_tensor(key, cfg, n, dtype)
+            for n in H.layout(cfg).global_shapes(cfg)}
+
+
+def layer_weights(key, cfg: dict, layer, dtype=jnp.float32,
+                  like=None) -> dict:
+    shapes = H.layout(cfg).layer_shapes(cfg, layer if like is None else like)
+    return {n: layer_tensor(key, cfg, n, layer, dtype, like) for n in shapes}
 
 
 def stacked_weights(key, cfg: dict, dtype) -> dict:
-    """Every layer's tensors stacked on a leading axis, and the global
-    tensors, in ``dtype``.  Call inside one ``jax.jit`` so that each f32
-    draw is cast as it is made."""
-    n = cfg["num_hidden_layers"]
-    layers = {name: jax.vmap(lambda l, name=name: layer_tensor(
-        key, cfg, name, l, dtype))(jnp.arange(n))
-        for name in layer_shapes(cfg)}
-    glob = {name: global_tensor(key, cfg, name, dtype)
-            for name in global_shapes(cfg)}
-    return {"layers": layers, **glob}
+    """Each group of like layers with its tensors stacked on a leading
+    axis (``groups``, in the layout's order), and the global tensors, in
+    ``dtype``.  Call inside one ``jax.jit`` so that each f32 draw is cast
+    as it is made."""
+    LY = H.layout(cfg)
+    groups = [{name: jax.vmap(lambda l, name=name, first=first: layer_tensor(
+        key, cfg, name, l, dtype, like=first))(jnp.arange(first, first + n))
+        for name in LY.layer_shapes(cfg, first)}
+        for first, n in LY.layer_groups(cfg)]
+    return {"groups": groups, **global_weights(key, cfg, dtype)}
