@@ -210,8 +210,10 @@ class TracedWindow:
 @dataclasses.dataclass
 class Record:
     """What one run measured, for the metric readers.  ``counts`` holds
-    the driver's counters over the window; ``trace`` the reduced profile
-    (traced runs only)."""
+    the driver's counters over the window, and the program's registry
+    (``obs.get().registry.snapshot()``) as it stood at the window's opening
+    and close, ``program_open`` and ``program_close``; ``trace`` the
+    reduced profile (traced runs only)."""
     cell: str
     config: dict
     traffic: dict
@@ -222,6 +224,15 @@ class Record:
     counts: dict
     trace: Optional[object] = None
     memory_peak_bytes: int = 0
+
+
+def program_delta(rec: Record, key: str) -> float:
+    """How far the program's registry value ``key`` (a counter, or a
+    timer's ``/count`` or ``/sum``) moved in the window: at the close less
+    at the opening, 0 for a key absent."""
+    c = rec.counts
+    return (c.get("program_close", {}).get(key, 0.0)
+            - c.get("program_open", {}).get(key, 0.0))
 
 
 def read_metrics(metrics: list, rec: Record) -> dict:

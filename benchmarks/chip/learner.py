@@ -25,6 +25,7 @@ import traffic as TR
 import weights as W
 from harness import Span, log
 
+from repro import obs
 from repro.core.grpo import GRPOConfig
 from repro.core.mdp import Role, Trajectory
 from repro.core.trainer import Learner
@@ -136,6 +137,7 @@ def program_part(cell, seed, seconds, traced, t_start, batches, fault):
     log(f"set-up steps done: {time.perf_counter() - t_start:.1f} s")
 
     window = H.TracedWindow(traced, cell.name, seed)
+    program_open = obs.get().registry.snapshot()
     window.open()
     cc0 = H.CompileCount().snapshot()
     spans.clear()
@@ -152,6 +154,7 @@ def program_part(cell, seed, seconds, traced, t_start, batches, fault):
     jax.block_until_ready(learner.params)
     t_close = time.perf_counter()
     window.close()
+    program_close = obs.get().registry.snapshot()
     cc = H.CompileCount.since(cc0)
     failed = int(sum(not np.isfinite(float(x)) for x in window_losses))
     log(f"window {t_close - t_open:.2f} s, {n_steps} steps, {n_tok} tokens; "
@@ -160,7 +163,9 @@ def program_part(cell, seed, seconds, traced, t_start, batches, fault):
         f"traces: {cc['names']}")
     return {"setup_s": t_open - t_start, "window_s": t_close - t_open,
             "counts": {"steps": n_steps, "tokens": n_tok, "keys": n_keys,
-                       "spans_s": dict(spans), "compiles": cc["compiled"]},
+                       "spans_s": dict(spans), "compiles": cc["compiled"],
+                       "program_open": program_open,
+                       "program_close": program_close},
             "attempted": n_steps, "failed": failed,
             "memory_peak_bytes": H.memory_peak_bytes(cell.chips),
             "trace_dir": window.dir if traced else None,

@@ -32,6 +32,7 @@ import traffic as TR
 import weights as W
 from harness import Span, log
 
+from repro import obs
 from repro.core import RolloutConfig, RolloutWorker
 from repro.core.async_engine import AsyncToolExecutor
 from repro.data.tokenizer import default_tokenizer
@@ -74,6 +75,7 @@ class Driver:
                   "traces": 0}
         self.compiled_names: list = []
         self.span_s: dict = {}
+        self.program_open = self.program_close = {}   # registry snapshots
         self.compiles = H.CompileCount()
         self.window: Optional[H.TracedWindow] = None
         self.session = None                   # the stream's decode session
@@ -90,6 +92,7 @@ class Driver:
     def round_start(self) -> None:
         now = time.perf_counter()
         if self.state == "warmup" and (self.row_retired > 0).all():
+            self.program_open = obs.get().registry.snapshot()
             self.window.open()
             self.state = "open"
             self.t_open = time.perf_counter()
@@ -106,6 +109,7 @@ class Driver:
                 "engine.generate", "engine.extend_rows", "engine.reset_rows",
                 "engine.start"))
             self.window.close()
+            self.program_close = obs.get().registry.snapshot()
             self.state = "closed"
             raise WindowClosed()
 
@@ -447,8 +451,9 @@ def run(cell: H.Cell, seed: int, seconds: float, traced: bool,
     numbers, extra = check(cfg, mix, seed, plan, drv, done, live, tok0,
                            manager, control)
     log(f"reference check: {time.perf_counter() - t_ref:.1f} s")
-    counts = dict(drv.c, finished=len(done),
-                  spans_s=dict(drv.span_s))
+    counts = dict(drv.c, finished=len(done), spans_s=dict(drv.span_s),
+                  program_open=drv.program_open,
+                  program_close=drv.program_close)
     return {"setup_s": setup_s, "window_s": drv.t_close - drv.t_open,
             "counts": counts, "numbers": numbers, "attempted": len(done),
             "failed": failed, "memory_peak_bytes": peak_bytes,

@@ -58,8 +58,10 @@ def execute(cell, seed, seconds, traced, device, **driver_kw) -> int:
             trace_reduce.xplane_file(out["trace_dir"]), cell.chips)
         shutil.rmtree(out["trace_dir"], ignore_errors=True)
         top = sorted(summary.module_s.items(), key=lambda kv: -kv[1])[:8]
+        spans = sorted(summary.span_s.items(), key=lambda kv: -kv[1])[:12]
         H.log(f"trace read in {time.perf_counter() - t0:.1f} s; programs "
-              f"by device seconds: {top}")
+              f"by device seconds: {top}; program spans by host seconds: "
+              f"{spans}")
     # off the chip (the CPU tests) no device metric is written
     on_chip = device["platform"] == "tpu"
     rec = H.Record(cell=cell.name, config=cell.config, traffic=cell.traffic,

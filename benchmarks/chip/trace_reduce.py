@@ -9,13 +9,22 @@
 * Each device op's self time (its duration less that of the ops nested in
   it, as a while loop holds its body) is summed by its HLO instruction
   name, and each program's time (``XLA Modules`` line) by its name.
+* The program's own host spans (``obs.span``) are the host events with a
+  slash-namespaced name, ``<namespace>/<step>`` (``engine/alloc``,
+  ``rollout/round``), whatever the namespace.  ``span_s`` sums each
+  name's seconds open inside the window, clipped to it; a span nested in
+  another counts under its own name as well as inside the outer one.
 * An idle gap is a stretch of the window with no op on the device; it is
-  named by the innermost benchmark host span open at its middle.
+  named by the innermost host span open at its middle, the benchmark's
+  (``engine.extend_rows``) or the program's (``engine/alloc``), or
+  ``host`` where none is.
 """
 from __future__ import annotations
 
 import dataclasses
 import glob
+import heapq
+import re
 import warnings
 from pathlib import Path
 from typing import Dict, List, Tuple
@@ -23,6 +32,8 @@ from typing import Dict, List, Tuple
 HOST_SPANS = ("engine.generate", "engine.extend_rows", "engine.reset_rows",
               "engine.start", "tools.drain", "learner.make_batch",
               "learner.step")
+# the program's span names; a profiler's Python-tracer events start "$"
+PROGRAM_SPAN = re.compile(r"[a-z][a-z0-9_]*/[^\s#$]+")
 
 
 @dataclasses.dataclass
@@ -33,6 +44,7 @@ class Summary:
     module_s: Dict[str, float]        # program name -> seconds (all chips)
     gaps: List[Tuple[str, float]]     # (host span, seconds), longest first
     n_devices: int
+    span_s: Dict[str, float]          # program span -> seconds in window
 
     def op_time(self, *needles: str) -> float:
         return sum(v for k, v in self.op_s.items()
@@ -90,23 +102,55 @@ def summarize(path: str, n_devices: int = 1) -> Summary:
         return _summarize(ProfileData.from_file(path), n_devices)
 
 
-def _summarize(pd, n_devices: int) -> Summary:
-    host, window = [], None
-    devices = []
+def host_spans(pd) -> tuple:
+    """The ``bench.window`` span's (start, end) and every benchmark or
+    program host span as (start, end, name), in ns on the trace's clock."""
+    spans, window = [], None
     for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:") and \
-                plane.name[len("/device:TPU:"):].isdigit():
-            devices.append(plane)
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name == "bench.window":
-                        window = (e.start_ns, e.start_ns + e.duration_ns)
-                    elif e.name in HOST_SPANS:
-                        host.append((e.start_ns, e.start_ns + e.duration_ns,
-                                     e.name))
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == "bench.window":
+                    window = (e.start_ns, e.start_ns + e.duration_ns)
+                elif e.name in HOST_SPANS or PROGRAM_SPAN.fullmatch(e.name):
+                    spans.append((e.start_ns, e.start_ns + e.duration_ns,
+                                  e.name))
     if window is None:
         raise ValueError("the trace holds no bench.window span")
+    return window, spans
+
+
+def span_seconds(spans, w0: float, w1: float) -> Dict[str, float]:
+    """Program span name -> its seconds open inside [w0, w1] (ns)."""
+    out: Dict[str, float] = {}
+    for s, e, name in spans:
+        t = min(e, w1) - max(s, w0)
+        if t > 0 and PROGRAM_SPAN.fullmatch(name):
+            out[name] = out.get(name, 0.0) + t * 1e-9
+    return out
+
+
+def innermost(spans, points) -> List[str]:
+    """For each of ``points`` (ascending, ns) the name of the shortest span
+    open at it (the first listed among equals), or ``host``."""
+    order = sorted(range(len(spans)), key=lambda i: spans[i][0])
+    heap, out, k = [], [], 0
+    for p in points:
+        while k < len(order) and spans[order[k]][0] <= p:
+            i = order[k]
+            heapq.heappush(heap, (spans[i][1] - spans[i][0], i))
+            k += 1
+        while heap and spans[heap[0][1]][1] < p:   # closed: for good,
+            heapq.heappop(heap)                    # as points ascend
+        out.append(spans[heap[0][1]][2] if heap else "host")
+    return out
+
+
+def _summarize(pd, n_devices: int) -> Summary:
+    window, host = host_spans(pd)
+    devices = [p for p in pd.planes if p.name.startswith("/device:TPU:")
+               and p.name[len("/device:TPU:"):].isdigit()]
     w0, w1 = window
     devices = sorted(devices, key=lambda p: int(p.name.split(":")[-1]))
     devices = devices[:n_devices]
@@ -140,25 +184,22 @@ def _summarize(pd, n_devices: int) -> Summary:
         busy_total += sum(t - s for s, t in merged) * 1e-9
         if first_busy is None:
             first_busy = merged
-    gaps = []
+    gaps = []                        # (middle, seconds), in time order
     cursor = w0
     for s, t in (first_busy or []) + [[w1, w1]]:
         if s > cursor:
-            mid = (cursor + s) / 2
-            open_spans = [h for h in host if h[0] <= mid <= h[1]]
-            name = (min(open_spans, key=lambda h: h[1] - h[0])[2]
-                    if open_spans else "host")
-            gaps.append((name, (s - cursor) * 1e-9))
+            gaps.append(((cursor + s) / 2, (s - cursor) * 1e-9))
         cursor = max(cursor, t)
-    gaps.sort(key=lambda g: -g[1])
+    names = innermost(host, [m for m, _ in gaps])
+    gaps = sorted(zip(names, (sec for _, sec in gaps)), key=lambda g: -g[1])
     return Summary(window_s=(w1 - w0) * 1e-9, busy_s=busy_total / len(devices),
                    op_s=op_s, module_s=module_s, gaps=gaps,
-                   n_devices=len(devices))
+                   n_devices=len(devices), span_s=span_seconds(host, w0, w1))
 
 
 def breakdown(s: Summary, n: int = 10) -> dict:
     """The device ops that took most time and the longest idle gaps by
-    the host span open in each, at most ``n`` of each."""
+    the innermost host span open in each, at most ``n`` of each."""
     ops = sorted(s.op_s.items(), key=lambda kv: -kv[1])[:n]
     return {"device_ops": [[k, v] for k, v in ops],
             "idle_gaps": [[k, v] for k, v in s.gaps[:n]]}
